@@ -28,6 +28,7 @@ import numpy as np
 from benchmarks.common import BenchRow, convex_problem
 from repro.core import engine, operators as ops, scenarios as scn
 from repro.data import worker_batches
+from repro.launch.mesh import make_mesh
 from repro.optim import constant, inverse_time, sgd
 from repro.train import RunConfig, train
 
@@ -70,7 +71,7 @@ def _steps_per_s(R, D=2048, T=16, warmup=4):
     inner = sgd()
     state = engine.init({"w": jnp.zeros(D)}, inner, R)
     if len(jax.devices()) > 1 and R % len(jax.devices()) == 0:
-        mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+        mesh = make_mesh((len(jax.devices()),), ("data",))
         state = engine.shard_worker_axis(state, mesh)
     step = engine.make_step(grad_fn, inner, ops.TopK(k=0.05), constant(0.05),
                             R, global_rounds=True,

@@ -41,6 +41,8 @@ def main() -> None:
                     help="also write BENCH_<suite>.json into DIR "
                          "(default: current directory)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for name, mod_name in SUITES:

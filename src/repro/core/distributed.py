@@ -38,15 +38,12 @@ State layout (leading axes refer to the *global* array view):
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any, Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import (MODERN, axis_size, round_scan_supported,
-                          shard_map, sharding_constraints_usable)
 from repro.core import bits as bitlib
 from repro.core import channel as chn
 from repro.core.operators import (
@@ -125,10 +122,7 @@ def axis_topk_compact(x: jnp.ndarray, k_frac: float, axis: int,
     construction when ``axis`` is unsharded.
 
     Sort-free on both routes: the compact Pallas kernel when the row
-    is eligible (``dispatch_cfg``), else the scatter-free jnp oracle —
-    either traces without ``lax.top_k``, which the 0.4.x SPMD
-    partitioner cannot partition inside partial-manual regions, so the
-    sparse-allgather aggregation runs on this container too.
+    is eligible (``dispatch_cfg``), else the scatter-free jnp oracle.
 
     Wire bits are *counted* from the actual survivors (exact zeros
     excluded), matching the dense path's ledger convention.
@@ -187,9 +181,8 @@ def axis_topk(x: jnp.ndarray, k_frac: float, axis: int,
     """Dense Top_k along ``axis`` via the bisection *threshold select*
     (kernels/ref.py; exact-k generically — DESIGN.md §3.1).
 
-    Sort- and scatter-free on purpose: ``lax.top_k`` hard-crashes the
-    0.4.x SPMD partitioner inside a partial-manual shard_map region,
-    and on TPU the threshold form is the fast path anyway (§3)."""
+    Sort- and scatter-free on purpose: on TPU the threshold form is the
+    fast path (§3)."""
     from repro.kernels.ref import topk_compress_ref
     return _threshold_axis_topk(
         x, k_frac, axis, sign_bits,
@@ -363,13 +356,10 @@ class ShardCompressor:
                 o, b = self._kernel_leaf(g, k_frac, ax, sign)
             else:
                 o, b = axis_topk(g, k_frac, ax, sign_bits=sign)
-            if spec is not None and sharding_constraints_usable():
+            if spec is not None:
                 # pin the densified update to the leaf's TP sharding: the
                 # top_k/scatter pair otherwise makes XLA re-shard (an
-                # all-gather per leaf — §Perf iteration 2 finding).  A
-                # constraint naming auto axes inside a partial-manual
-                # region crashes the 0.4.x SPMD partitioner, so the pin
-                # is modern-jax only (pure perf, not correctness).
+                # all-gather per leaf — §Perf iteration 2 finding)
                 entries = list(spec) + [None] * (g.ndim - len(tuple(spec)))
                 o = jax.lax.with_sharding_constraint(o, P(*entries))
             outs.append(o)
@@ -447,14 +437,6 @@ class ShardCompressor:
             meta.append(("sparse", ax, moved))
         return meta
 
-    def would_kernel_dispatch(self) -> bool:
-        """Could this compressor launch Pallas kernels as configured?
-        (the 0.4.x TP>1 dense-psum guard's probe)"""
-        if self.is_identity() or self.dispatch == "reference":
-            return False
-        return self.dispatch == "kernel" or (
-            self.dispatch == "auto" and jax.default_backend() == "tpu")
-
     def gamma(self) -> float:
         if self.mode == "policy":
             gs = []
@@ -474,42 +456,6 @@ class ShardCompressor:
 # ---------------------------------------------------------------------------
 # engine
 # ---------------------------------------------------------------------------
-
-
-_TP_KERNEL_WARNED = set()
-
-
-def _legacy_tp_kernel_guard(compressor: Optional[ShardCompressor], mesh,
-                            daxes, wire: str,
-                            direction: str = "uplink"):
-    """0.4.x partial-manual guard (ROADMAP known issue): on TP>1 legacy
-    meshes the ``dense_psum`` sync body cannot host Pallas kernels —
-    the uplink output feeds an in-body ``pmean`` over an auto-axis-
-    sharded operand, and even the downlink's collective-free kernel
-    launches trip the same ``IsManualSubgroup`` CHECK inside that
-    region (reproduced; only the compact sparse path, whose buffers
-    leave via out_specs, lowers with kernels there).  Auto-downgrade
-    the affected channel to reference dispatch with a one-time warning
-    per direction instead of hard-crashing — outputs and ledger are
-    identical, only speed differs.
-    """
-    if MODERN or wire != "dense_psum" or compressor is None:
-        return compressor
-    tp = any(mesh.shape[a] > 1 for a in mesh.axis_names if a not in daxes)
-    if not (tp and compressor.would_kernel_dispatch()):
-        return compressor
-    if direction not in _TP_KERNEL_WARNED:
-        warnings.warn(
-            "ShardCompressor(dispatch=%r) with dense psum aggregation "
-            "cannot run the Pallas kernels inside a 0.4.x partial-manual "
-            "region with a >1 tensor-parallel axis (XLA IsManualSubgroup); "
-            "downgrading the %s to reference dispatch. Use "
-            "aggregate='sparse_allgather' (kernel-capable there) or a "
-            "modern jax to keep the kernel path."
-            % (compressor.dispatch, direction),
-            stacklevel=3)
-        _TP_KERNEL_WARNED.add(direction)
-    return dataclasses.replace(compressor, dispatch="reference")
 
 
 def worker_count(mesh, data_axes: Sequence[str]) -> int:
@@ -607,9 +553,6 @@ def make_dist_steps(
     daxes = tuple(data_axes)
     R = worker_count(mesh, daxes)
     manual = set(daxes)
-    compressor = _legacy_tp_kernel_guard(compressor, mesh, daxes, wire)
-    downlink = _legacy_tp_kernel_guard(downlink, mesh, daxes, wire,
-                                       direction="downlink")
     up = chn.ShardChannel(compressor, "uplink")
     down = chn.ShardChannel(downlink, "downlink")
     down_active = not down.is_identity()
@@ -700,15 +643,13 @@ def make_dist_steps(
             g)
 
     # ---- sync step ------------------------------------------------------
-    def make_sync_body(z1, pregathered: bool = False,
-                       with_down: bool = False):
+    def make_sync_body(z1, with_down: bool = False):
       """Dense sync body.  With ``with_down`` (compressed downlink
       channel, DESIGN.md §5) the signature gains (view, down_mem): the
       uplink delta is taken against the worker's lagging *view*
       x_t^{(r)}, and after the master update the server compresses each
       worker's master delta against its error memory md^{(r)} — all
-      shard-local threshold selection, sort- and collective-free, so
-      the body stays partition-safe on 0.4.x partial-manual meshes.
+      shard-local threshold selection, sort- and collective-free.
 
       With ``partial`` (closure) the signature additionally gains a
       worker-sharded sync_mask and carries the view even without a
@@ -727,10 +668,8 @@ def make_dist_steps(
                                        batch, lr)
         mem = _squeeze(memory)
         # zero1 masters are sharded on axis 0 over the worker axes:
-        # materialize the full master for the delta via all_gather —
-        # unless the caller already replicated it in the auto region
-        # (0.4.x cannot partition all_gather inside partial-manual).
-        full_master = master if pregathered else _gather_master(master, z1)
+        # materialize the full master for the delta via all_gather
+        full_master = _gather_master(master, z1)
         ref = _squeeze(view) if carry_view else full_master
         delta = jax.tree_util.tree_map(
             lambda m, x, h: m + x.astype(jnp.float32) - h.astype(jnp.float32),
@@ -820,8 +759,13 @@ def make_dist_steps(
         """``extra_worker`` counts additional worker-sharded operands
         threaded between the core state and (step, batch, key): the
         downlink channel state (view, down_memory) and/or the per-step
-        sync mask of a partial-participation run."""
-        return shard_map(
+        sync mask of a partial-participation run.
+
+        Every engine region runs with ``check_vma=False``: the Pallas
+        compressors and their jnp oracles loop (``fori_loop``) over
+        carries that are traced without varying-axis types, and the
+        interpret-mode kernels cannot be typed at all."""
+        return jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -831,7 +775,7 @@ def make_dist_steps(
             ),
             out_specs=out_specs,
             axis_names=manual,
-            check_vma=True,
+            check_vma=False,
         )
 
     def _bits_down_of(state):
@@ -874,16 +818,6 @@ def make_dist_steps(
         m = _prep_mask(sync_mask) if partial else None
         z1 = _z1mask(state.master)
         mspecs = _master_in_specs(z1)
-        master_in = state.master
-        in_mspecs = mspecs
-        pregather = zero1 and not MODERN
-        if pregather:
-            # replicate the zero1 master in the auto region (XLA inserts
-            # the all-gather there); the body then skips its own gather
-            master_in = jax.tree_util.tree_map(
-                lambda x: jax.lax.with_sharding_constraint(
-                    x, NamedSharding(mesh, P())), state.master)
-            in_mspecs = P()
         extra_in = ()
         if carry_view:
             extra_in += (state.view,)
@@ -894,13 +828,13 @@ def make_dist_steps(
         rounds_inc = jnp.any(m).astype(jnp.int32) if partial else 1
         if down_active:
             sync_mapped = _shmap(
-                make_sync_body(z1, pregather, with_down=True), in_mspecs,
+                make_sync_body(z1, with_down=True), mspecs,
                 (mspecs, worker_specs, worker_specs, worker_specs,
                  worker_specs, worker_specs, P(), P(), P()),
                 extra_worker=len(extra_in))
             (master, local, memory, inner_new, view, down_mem, wire_bits,
              down_bits, loss) = sync_mapped(
-                master_in, state.local, state.memory, state.inner,
+                state.master, state.local, state.memory, state.inner,
                 *extra_in, state.step, batch, key,
             )
             return (
@@ -918,10 +852,10 @@ def make_dist_steps(
         if carry_view:
             out_specs = out_specs + (worker_specs,)
         sync_mapped = _shmap(
-            make_sync_body(z1, pregather), in_mspecs,
+            make_sync_body(z1), mspecs,
             out_specs + (P(), P()), extra_worker=len(extra_in))
         out = sync_mapped(
-            master_in, state.local, state.memory, state.inner,
+            state.master, state.local, state.memory, state.inner,
             *extra_in, state.step, batch, key,
         )
         if carry_view:
@@ -952,7 +886,7 @@ def make_dist_steps(
     # region.  The dense mean is reconstructed in the auto region, so
     # the wire carries W*kcap entries per row instead of a dense-f32
     # ring all-reduce.  Sort-free end to end: the traced step contains
-    # no lax.top_k, so it partitions under 0.4.x too.
+    # no lax.top_k.
     def _leaf_meta(master_tree, comp: Optional[ShardCompressor] = None):
         comp = compressor if comp is None else comp
         return comp.leaf_meta(master_tree, param_specs)
@@ -1021,7 +955,7 @@ def make_dist_steps(
       emitted in the compact (idx, val) wire form (DESIGN.md §3.3) so
       the buffers leave via out_specs and the dense decode happens in
       the auto region — sort-free, collective-free (bar the scalar
-      bits psum), partition-safe on 0.4.x."""
+      bits psum)."""
       def down_body(new_master, view, down_mem, *rest):
         rest = list(rest)
         smask = rest.pop(0) if partial else None
@@ -1061,13 +995,13 @@ def make_dist_steps(
             extra_in += (m,)
             extra_specs += (worker_specs,)
         half_specs = (worker_specs,) if partial else ()
-        mapped = shard_map(
+        mapped = jax.shard_map(
             make_sparse_sync_body(z1), mesh=mesh,
             in_specs=(_master_in_specs(z1), worker_specs, worker_specs,
                       worker_specs) + extra_specs + (P(), batch_spec, P()),
             out_specs=(worker_specs, worker_specs) + half_specs
             + ([P(tuple(daxes))] * n_arrays, P(), P()),
-            axis_names=manual, check_vma=True,
+            axis_names=manual, check_vma=False,
         )
         out = mapped(
             state.master, state.local, state.memory, state.inner,
@@ -1194,19 +1128,19 @@ def make_dist_steps(
         n_down = sum(1 if mt[0] == "dense" else 2 for mt in dmeta)
         master_in = new_master
         if zero1:
-            # replicate the (z1-sharded) new master in the auto region
-            # before entry: 0.4.x partial-manual cannot gather in-body
+            # replicate the (z1-sharded) new master in the auto region:
+            # the down body takes the full master (in_specs P())
             master_in = jax.tree_util.tree_map(
                 lambda x: jax.lax.with_sharding_constraint(
                     x, NamedSharding(mesh, P())), new_master)
         mask_in = (smask,) if partial else ()
         mask_specs = (worker_specs,) if partial else ()
-        down_mapped = shard_map(
+        down_mapped = jax.shard_map(
             make_sparse_down_body(), mesh=mesh,
             in_specs=(P(), worker_specs, worker_specs)
             + mask_specs + (P(),),
             out_specs=(worker_specs, [P(tuple(daxes))] * n_down, P()),
-            axis_names=manual, check_vma=True,
+            axis_names=manual, check_vma=False,
         )
         down_mem, darrays, down_bits = down_mapped(
             master_in, state.view, state.down_memory, *mask_in, key)
@@ -1273,30 +1207,29 @@ def make_dist_steps(
         out_specs = (_master_in_specs(z1), worker_specs, worker_specs,
                      worker_specs)
         out_specs += (worker_specs,) * (int(carry_view) + int(down_active))
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh, in_specs=(P(),),
             out_specs=out_specs,
-            axis_names=manual, check_vma=True,
+            axis_names=manual, check_vma=False,
         )
-        # eager shard_map with auto (non-manual) axes is unimplemented on
-        # older jax; under jit it lowers fine on every version
+        # eager shard_map with auto (non-manual) axes is unimplemented;
+        # under jit it lowers
         out = jax.jit(mapped)(params)
         master, local, memory, inner = out[:4]
         view = out[4] if carry_view else None
         down_mem = out[4 + int(carry_view)] if down_active else None
+        # counters committed to the mesh like the ones a step returns,
+        # so the second call of a jitted step does not compile again
+        zero = lambda dt: jax.device_put(  # noqa: E731
+            jnp.zeros((), dt), NamedSharding(mesh, P()))
         return DistQsparseState(
             master=master, local=local, memory=memory, inner=inner,
-            step=jnp.zeros((), jnp.int32),
-            bits=jnp.zeros((), jnp.float32),
-            rounds=jnp.zeros((), jnp.int32),
-            view=view, down_memory=down_mem,
-            bits_down=jnp.zeros((), jnp.float32),
+            step=zero(jnp.int32), bits=zero(jnp.float32),
+            rounds=zero(jnp.int32), view=view, down_memory=down_mem,
+            bits_down=zero(jnp.float32),
         )
 
     return init_fn, local_step, sync_step
-
-
-_ROUND_FALLBACK_WARNED = set()
 
 
 def _make_round_core(local_step, sync_step):
@@ -1339,7 +1272,7 @@ def make_dist_round(
 ):
     """Round-program runtime for the mesh engine (DESIGN.md §7).
 
-    Returns ``(init_fn, round_fn, fused)``.  ``round_fn(state,
+    Returns ``(init_fn, round_fn)``.  ``round_fn(state,
     batch_block, key) -> (state, losses[L], key)`` executes one sync
     round — L−1 local steps then the sync step at the tail, where L is
     the block's leading dim (the host schedule guarantees the tail is
@@ -1354,69 +1287,26 @@ def make_dist_round(
     callers passing a wire format as ``aggregate=`` are shimmed with a
     one-time warning (see make_dist_steps).
 
-    With ``fused`` (modern jax, or a legacy mesh whose tensor-parallel
-    axes are all size 1 — ``compat.round_scan_supported``) the whole
-    round is ONE donated jitted program: ``lax.scan`` over the
-    shard_mapped local step with the batch block as xs, the shard_mapped
-    sync step once at the tail, per-step losses accumulated on device
-    and the PRNG key split in-program with the host loop's sequence —
-    bit-for-bit the per-step trajectories.  On 0.4.x TP>1 meshes the
-    legacy SPMD partitioner cannot partition scan-with-xs around the
-    partial-manual steps (ROADMAP known issue), so ``round_fn``
-    degrades to the per-step host composition (identical math and key
-    stream, only dispatch overhead differs) with a one-time warning.
+    The whole round is ONE donated jitted program: ``lax.scan`` over
+    the shard_mapped local step with the batch block as xs, the
+    shard_mapped sync step once at the tail, per-step losses
+    accumulated on device and the PRNG key split in-program with the
+    host loop's sequence — bit-for-bit the per-step trajectories.
     """
     init_fn, local_step, sync_step = make_dist_steps(
         grad_fn, inner_opt, compressor, lr_schedule, mesh, data_axes,
         param_specs, zero1=zero1, aggregate=aggregate, downlink=downlink,
         wire=wire, partial=partial)
-    fused = round_scan_supported(mesh, data_axes)
-
-    if fused:
-        round_core = _make_round_core(local_step, sync_step)
-
-        if partial:
-            def round_program(state, batch_block, tail_mask, key):
-                return round_core(state, batch_block, key, tail_mask)
-        else:
-            round_program = round_core
-
-        from repro.core.engine import donated_jit
-        return init_fn, donated_jit(round_program), True
-
-    if "round" not in _ROUND_FALLBACK_WARNED:
-        warnings.warn(
-            "the fused round program (lax.scan over the shard_mapped "
-            "local step) cannot be partitioned on a 0.4.x jax mesh with "
-            "a >1 tensor-parallel axis; falling back to per-step "
-            "dispatch — identical trajectories, only host overhead "
-            "differs. Use a TP=1 mesh or a modern jax for the fused "
-            "path.", stacklevel=2)
-        _ROUND_FALLBACK_WARNED.add("round")
-    from repro.core.engine import donated_jit
-    ls = donated_jit(local_step)
-    ss = donated_jit(sync_step)
-
-    def fallback_core(state, batch_block, key, *tail_mask):
-        L = jax.tree_util.tree_leaves(batch_block)[0].shape[0]
-        losses = []
-        for i in range(L):
-            batch = jax.tree_util.tree_map(lambda x, i=i: x[i], batch_block)
-            key, sub = jax.random.split(key)
-            if i == L - 1:
-                state, loss = ss(state, batch, sub, *tail_mask)
-            else:
-                state, loss = ls(state, batch, sub)
-            losses.append(loss)
-        return state, jnp.stack(losses), key
+    round_core = _make_round_core(local_step, sync_step)
 
     if partial:
-        def round_fallback(state, batch_block, tail_mask, key):
-            return fallback_core(state, batch_block, key, tail_mask)
+        def round_program(state, batch_block, tail_mask, key):
+            return round_core(state, batch_block, key, tail_mask)
     else:
-        round_fallback = fallback_core
+        round_program = round_core
 
-    return init_fn, round_fallback, False
+    from repro.core.engine import donated_jit
+    return init_fn, donated_jit(round_program)
 
 
 def make_dist_multiround(
@@ -1437,7 +1327,7 @@ def make_dist_multiround(
     driver's compiled unit (DESIGN.md §10, the mesh twin of
     ``engine.make_multiround``).
 
-    Returns ``(init_fn, multiround_fn, fused)``.  ``multiround_fn``
+    Returns ``(init_fn, multiround_fn)``.  ``multiround_fn``
     takes ``(state, blocks, key)`` — or ``(state, blocks, tail_masks,
     key)`` with ``partial=True`` — where ``blocks`` stacks W
     equal-length round blocks ([W, L, ...] leaves) and ``tail_masks``
@@ -1452,81 +1342,34 @@ def make_dist_multiround(
     serialized ``make_dist_round`` program jits, threading the same key
     stream, so states, losses and both wire ledgers match the per-round
     driver exactly.
-
-    On a 0.4.x mesh with a >1 tensor-parallel axis the round core
-    itself cannot be partitioned (``compat.round_scan_supported``;
-    ROADMAP known issue), so windows degrade to a host loop over the
-    per-round fallback — identical trajectories, no overlap — with a
-    one-time warning, and ``fused`` is False.
     """
     init_fn, local_step, sync_step = make_dist_steps(
         grad_fn, inner_opt, compressor, lr_schedule, mesh, data_axes,
         param_specs, zero1=zero1, aggregate=aggregate, downlink=downlink,
         wire=wire, partial=partial)
-    fused = round_scan_supported(mesh, data_axes)
     from repro.core.engine import donated_jit
+    round_core = _make_round_core(local_step, sync_step)
 
-    if fused:
-        round_core = _make_round_core(local_step, sync_step)
+    def multi_core(state, blocks, key, *tail_masks):
+        def body(carry, xs):
+            st, kk = carry
+            if tail_masks:
+                block, mask = xs
+                st, ls, kk = round_core(st, block, kk, mask)
+            else:
+                st, ls, kk = round_core(st, xs, kk)
+            return (st, kk), ls
 
-        def multi_core(state, blocks, key, *tail_masks):
-            def body(carry, xs):
-                st, kk = carry
-                if tail_masks:
-                    block, mask = xs
-                    st, ls, kk = round_core(st, block, kk, mask)
-                else:
-                    st, ls, kk = round_core(st, xs, kk)
-                return (st, kk), ls
-
-            xs = (blocks, tail_masks[0]) if tail_masks else blocks
-            (state, key), losses = jax.lax.scan(body, (state, key), xs)
-            return state, losses, key
-
-        if partial:
-            def multiround(state, blocks, tail_masks, key):
-                return multi_core(state, blocks, key, tail_masks)
-        else:
-            multiround = multi_core
-        return init_fn, donated_jit(multiround), True
-
-    if "multiround" not in _ROUND_FALLBACK_WARNED:
-        warnings.warn(
-            "the windowed multi-round program cannot be partitioned on "
-            "a 0.4.x jax mesh with a >1 tensor-parallel axis; windows "
-            "fall back to per-round dispatch — identical trajectories, "
-            "no compute/comm overlap. Use a TP=1 mesh or a modern jax.",
-            stacklevel=2)
-        _ROUND_FALLBACK_WARNED.add("multiround")
-    ls_fb = donated_jit(local_step)
-    ss_fb = donated_jit(sync_step)
-
-    def window_fallback(state, blocks, key, *tail_masks):
-        W = jax.tree_util.tree_leaves(blocks)[0].shape[0]
-        all_losses = []
-        for w in range(W):
-            block = jax.tree_util.tree_map(lambda x, w=w: x[w], blocks)
-            L = jax.tree_util.tree_leaves(block)[0].shape[0]
-            losses = []
-            for i in range(L):
-                batch = jax.tree_util.tree_map(
-                    lambda x, i=i: x[i], block)
-                key, sub = jax.random.split(key)
-                if i == L - 1:
-                    tm = ((tail_masks[0][w],) if tail_masks else ())
-                    state, loss = ss_fb(state, batch, sub, *tm)
-                else:
-                    state, loss = ls_fb(state, batch, sub)
-                losses.append(loss)
-            all_losses.append(jnp.stack(losses))
-        return state, jnp.stack(all_losses), key
+        xs = (blocks, tail_masks[0]) if tail_masks else blocks
+        (state, key), losses = jax.lax.scan(body, (state, key), xs)
+        return state, losses, key
 
     if partial:
-        def multiround_fb(state, blocks, tail_masks, key):
-            return window_fallback(state, blocks, key, tail_masks)
+        def multiround(state, blocks, tail_masks, key):
+            return multi_core(state, blocks, key, tail_masks)
     else:
-        multiround_fb = window_fallback
-    return init_fn, multiround_fb, False
+        multiround = multi_core
+    return init_fn, donated_jit(multiround)
 
 
 # ---------------------------------------------------------------------------
@@ -1611,7 +1454,6 @@ def make_dist_fault_steps(
     daxes = tuple(data_axes)
     R = worker_count(mesh, daxes)
     manual = set(daxes)
-    compressor = _legacy_tp_kernel_guard(compressor, mesh, daxes, wire)
     up = chn.ShardChannel(compressor, "uplink")
     down = chn.ShardChannel(None, "downlink")
     Dq = int(queue_depth)
@@ -1716,13 +1558,13 @@ def make_dist_fault_steps(
     def fault_local_step(state: DistQsparseState, batch, row, key):
         _check_queue(state)
         rows = _row_arrays(row)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local_fault_body, mesh=mesh,
             in_specs=(P(), worker_specs, worker_specs, worker_specs,
                       worker_specs) + (worker_specs,) * 5
             + (P(), batch_spec, P()),
             out_specs=(worker_specs,) * 4 + (P(),),
-            axis_names=manual, check_vma=True,
+            axis_names=manual, check_vma=False,
         )
         local, memory, inner, view, loss = mapped(
             state.master, state.local, state.memory, state.inner,
@@ -1807,14 +1649,14 @@ def make_dist_fault_steps(
     def fault_sync_step_dense(state: DistQsparseState, batch, row, key):
         _check_queue(state)
         rows = _row_arrays(row)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             dense_fault_body, mesh=mesh,
             in_specs=(P(), worker_specs, worker_specs, worker_specs,
                       worker_specs, worker_specs, worker_specs,
                       worker_specs) + (worker_specs,) * 5
             + (P(), batch_spec, P()),
             out_specs=(P(),) + (worker_specs,) * 8 + (P(), P()),
-            axis_names=manual, check_vma=True,
+            axis_names=manual, check_vma=False,
         )
         (master, local, memory, inner, view, q, arrive, tau, arr_any,
          wire_bits, loss) = mapped(
@@ -1854,14 +1696,14 @@ def make_dist_fault_steps(
         sync_r, delay_r, alive_r, drop_r, _rec = rows
         meta = compressor.leaf_meta(state.master, param_specs)
         n_arrays = sum(1 if mt[0] == "dense" else 2 for mt in meta)
-        mapped = shard_map(
+        mapped = jax.shard_map(
             sparse_fault_body, mesh=mesh,
             in_specs=(P(), worker_specs, worker_specs, worker_specs,
                       worker_specs) + (worker_specs,) * 5
             + (P(), batch_spec, P()),
             out_specs=(worker_specs,) * 4
             + ([P(tuple(daxes))] * n_arrays, P(), P()),
-            axis_names=manual, check_vma=True,
+            axis_names=manual, check_vma=False,
         )
         half_all, memory, inner, view, arrays, wire_bits, loss = mapped(
             state.master, state.local, state.memory, state.inner,
@@ -2004,10 +1846,10 @@ def make_dist_fault_steps(
             return tuple(out)
 
         nq = 1 if wire == "dense_psum" else 0
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body, mesh=mesh, in_specs=(P(),),
             out_specs=(P(),) + (worker_specs,) * (4 + nq + 2),
-            axis_names=manual, check_vma=True,
+            axis_names=manual, check_vma=False,
         )
         out = jax.jit(mapped)(params)
         master, local, memory, inner, view = out[:5]
@@ -2066,70 +1908,40 @@ def make_dist_fault_round(
     *event* step (``rounds.compile_fault_rounds``), so the scanned head
     is pure fault-local steps and the tail one fault-sync step.
 
-    Returns ``(init_fn, round_fn, fused)`` with ``round_fn(state,
+    Returns ``(init_fn, round_fn)`` with ``round_fn(state,
     batch_block, row_block, key) -> (state, losses[L], key)`` —
     ``row_block`` an ``engine.FaultRow`` of [L, R] arrays (stacked per
     step, ``engine.index_rows(rows, slice(start, stop))``).  Bit-for-bit
-    the per-step fault trajectories; on 0.4.x TP>1 meshes degrades to
-    the per-step host composition like ``make_dist_round``.
+    the per-step fault trajectories.
     """
     init_fn, fls, fss = make_dist_fault_steps(
         grad_fn, inner_opt, compressor, lr_schedule, mesh, data_axes,
         param_specs, queue_depth=queue_depth, aggregate=aggregate,
         wire=wire, staleness_weight=staleness_weight)
     from repro.core.engine import FaultRow, donated_jit
-    fused = round_scan_supported(mesh, data_axes)
 
-    def _tail(rows):
-        return FaultRow(*(jnp.asarray(x)[-1] for x in rows))
-
-    if fused:
-        def round_program(state, batch_block, row_block, key):
-            rows = FaultRow(*(jnp.asarray(x) for x in row_block))
-
-            def body(carry, xs):
-                state, key = carry
-                batch, row = xs
-                key, sub = jax.random.split(key)
-                state, loss = fls(state, batch, row, sub)
-                return (state, key), loss
-
-            head_b = jax.tree_util.tree_map(lambda x: x[:-1], batch_block)
-            head_r = FaultRow(*(x[:-1] for x in rows))
-            tail_b = jax.tree_util.tree_map(lambda x: x[-1], batch_block)
-            (state, key), head_losses = jax.lax.scan(
-                body, (state, key), (head_b, head_r))
-            key, sub = jax.random.split(key)
-            state, tail_loss = fss(state, tail_b, _tail(rows), sub)
-            return (state, jnp.concatenate([head_losses,
-                                            tail_loss[None]]), key)
-
-        return init_fn, donated_jit(round_program), True
-
-    if "fault_round" not in _ROUND_FALLBACK_WARNED:
-        warnings.warn(
-            "the fused fault round program cannot be partitioned on a "
-            "0.4.x jax mesh with a >1 tensor-parallel axis; falling "
-            "back to per-step dispatch — identical trajectories, only "
-            "host overhead differs.", stacklevel=2)
-        _ROUND_FALLBACK_WARNED.add("fault_round")
-    jls = donated_jit(fls)
-    jss = donated_jit(fss)
-
-    def round_fallback(state, batch_block, row_block, key):
+    def round_program(state, batch_block, row_block, key):
         rows = FaultRow(*(jnp.asarray(x) for x in row_block))
-        L = jax.tree_util.tree_leaves(batch_block)[0].shape[0]
-        losses = []
-        for i in range(L):
-            batch = jax.tree_util.tree_map(lambda x, i=i: x[i], batch_block)
-            row = FaultRow(*(x[i] for x in rows))
-            key, sub = jax.random.split(key)
-            fn = jss if i == L - 1 else jls
-            state, loss = fn(state, batch, row, sub)
-            losses.append(loss)
-        return state, jnp.stack(losses), key
 
-    return init_fn, round_fallback, False
+        def body(carry, xs):
+            state, key = carry
+            batch, row = xs
+            key, sub = jax.random.split(key)
+            state, loss = fls(state, batch, row, sub)
+            return (state, key), loss
+
+        head_b = jax.tree_util.tree_map(lambda x: x[:-1], batch_block)
+        head_r = FaultRow(*(x[:-1] for x in rows))
+        tail_b = jax.tree_util.tree_map(lambda x: x[-1], batch_block)
+        (state, key), head_losses = jax.lax.scan(
+            body, (state, key), (head_b, head_r))
+        key, sub = jax.random.split(key)
+        tail = FaultRow(*(x[-1] for x in rows))
+        state, tail_loss = fss(state, tail_b, tail, sub)
+        return (state, jnp.concatenate([head_losses,
+                                        tail_loss[None]]), key)
+
+    return init_fn, donated_jit(round_program)
 
 
 def _zero1_axis(shape, spec, W: int):
@@ -2155,24 +1967,11 @@ def _allgather_axis(x, daxes, axis):
 
 def _shard_axis(x, daxes, axis):
     """Keep only this worker's slice along ``axis`` (inverse gather)."""
-    if MODERN:
-        n = 1
-        idx = 0
-        for a in daxes:
-            size = axis_size(a)
-            idx = idx * size + jax.lax.axis_index(a)
-            n *= size
-        shard = x.shape[axis] // n
-        return jax.lax.dynamic_slice_in_dim(x, idx * shard, shard, axis=axis)
-    # 0.4.x partial-manual regions cannot lower axis_index (PartitionId
-    # is unsupported under SPMD).  The operand is replicated over the
-    # worker axes here, so psum_scatter per axis (summing `size`
-    # identical copies) then one division recovers this worker's slice.
-    # Exact for power-of-two axis sizes; otherwise the single division
-    # costs at most 1 ulp per element per axis.
-    g = x
+    n = 1
+    idx = 0
     for a in daxes:
-        size = axis_size(a)
-        g = jax.lax.psum_scatter(
-            g, a, scatter_dimension=axis, tiled=True) / size
-    return g
+        size = jax.lax.axis_size(a)
+        idx = idx * size + jax.lax.axis_index(a)
+        n *= size
+    shard = x.shape[axis] // n
+    return jax.lax.dynamic_slice_in_dim(x, idx * shard, shard, axis=axis)

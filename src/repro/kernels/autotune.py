@@ -63,9 +63,9 @@ TABLE_VERSION = 1
 DEFAULT_TABLE_DIR = os.path.join("artifacts", "tuning")
 
 #: dense kernels hold 3 f32 blocks of (block_rows, row_len) in VMEM;
-#: candidates stay inside the envelope the historical defaults implied
-#: (block_rows 8 at max_row 2^19)
-VMEM_DENSE_BYTES = 3 * 8 * (1 << 19) * 4
+#: candidates stay inside the envelope of the dispatch defaults
+#: (block_rows 8 at max_row 2^17, which compiles for v5e)
+VMEM_DENSE_BYTES = 3 * 8 * (1 << 17) * 4
 #: the compact kernel's (block_rows, chunk, kcap) one-hot scatter
 #: intermediate, at the historical default geometry (8, 128, max_cap)
 VMEM_COMPACT_BYTES = 8 * 128 * (1 << 11) * 4

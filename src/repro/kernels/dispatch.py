@@ -100,7 +100,9 @@ class DispatchConfig:
           "reference" — never dispatch (pure core/operators.py)
     min_size: smallest leaf (elements) worth a kernel launch in "auto"
     max_row:  longest kernel row (elements); bounds VMEM residency —
-              3 f32 blocks of (block_rows, max_row) must fit in ~16 MB
+              at block_rows 8 the Top_k, compact and QSGD kernels
+              compile for v5e inside ``topk_compress.VMEM_LIMIT_BYTES``
+              up to 2^17 (tests/test_tpu_compile.py)
     max_cap:  largest compact survivor capacity (elements per row) the
               compact kernel accepts; bounds the (block_rows, chunk,
               kcap) one-hot intermediate of the slot scatter
@@ -118,7 +120,7 @@ class DispatchConfig:
 
     mode: str = "auto"
     min_size: int = 1 << 16
-    max_row: int = 1 << 19
+    max_row: int = 1 << 17
     max_cap: int = 1 << 11
     block_rows: Optional[int] = None
     pack: bool = True
@@ -397,6 +399,39 @@ def compact_rows_eligible(row_len: int, kcap: int,
     return rows_eligible(row_len, cfg, leaf_size) and kcap <= cfg.max_cap
 
 
+def _split_rows(kernel: Callable, rows: jnp.ndarray):
+    """Run a row-independent Pallas kernel over ``rows`` [R, n] inside
+    the current mesh context.  A Mosaic call cannot be partitioned by
+    XLA, so where the context mesh still has Auto axes (the mesh
+    engine's 'model' axis inside its worker-manual region) the rows are
+    split over those axes by a nested ``shard_map`` — Corollary 1's
+    piecewise compression, shard by shard — zero-padded to a multiple
+    of their size.  Every output of ``kernel`` leads with the row axis.
+
+    Trace such a program without a ``jax.set_mesh`` context (the mesh
+    engine's shard_maps name their mesh): under one, jax lowers the
+    nested region against the context's all-Auto device mesh, Mosaic
+    sees only the inner axes as manual, and the TPU compile is refused.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = () if mesh.empty else tuple(
+        a for a, t in zip(mesh.axis_names, mesh.axis_types)
+        if t == jax.sharding.AxisType.Auto)
+    if not auto:
+        return kernel(rows)
+    n_shards = 1
+    for a in auto:
+        n_shards *= mesh.shape[a]
+    r = rows.shape[0]
+    pad = (-r) % n_shards
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    spec = jax.sharding.PartitionSpec(auto)
+    out = jax.shard_map(kernel, mesh=mesh, in_specs=spec, out_specs=spec,
+                        axis_names=set(auto), check_vma=False)(rows)
+    return jax.tree_util.tree_map(lambda o: o[:r], out)
+
+
 def topk_rows(rows: jnp.ndarray, k: int, *, sign: bool = False,
               cfg: Optional[DispatchConfig] = None):
     """Kernel Top_k/SignTop_k over pre-shaped [rows, n] blocks.
@@ -407,8 +442,9 @@ def topk_rows(rows: jnp.ndarray, k: int, *, sign: bool = False,
     cfg = _resolve(cfg)
     br = _block_rows(cfg, "topk_compress", rows.shape[0], rows.shape[1],
                      k, sign)
-    return _topk.topk_compress(
-        rows, k, sign=sign, block_rows=br, interpret=cfg._interpret())
+    return _split_rows(functools.partial(
+        _topk.topk_compress, k=k, sign=sign, block_rows=br,
+        interpret=cfg._interpret()), rows)
 
 
 def compact_rows(rows: jnp.ndarray, k: int, kcap: int, *,
@@ -419,9 +455,8 @@ def compact_rows(rows: jnp.ndarray, k: int, kcap: int, *,
 
     Kernel when :func:`compact_rows_eligible`, else the scatter-free
     jnp oracle (``ref.topk_compact_ref``) — identical outputs either
-    way, and both forms are sort-free (they trace without ``lax.top_k``,
-    which the 0.4.x SPMD partitioner cannot partition inside
-    partial-manual shard_map regions).
+    way, and both forms are sort-free (they trace without
+    ``lax.top_k``).
 
     Returns (idx [rows, kcap] int32, val [rows, kcap] f32,
     new_mem [rows, n] f32, cnt [rows] int32); empty slots carry the
@@ -431,9 +466,9 @@ def compact_rows(rows: jnp.ndarray, k: int, kcap: int, *,
     n = rows.shape[1]
     if compact_rows_eligible(n, kcap, cfg, leaf_size=leaf_size):
         br, chunk = _compact_geometry(cfg, rows.shape[0], n, k, sign)
-        return _topk.topk_compact(
-            rows, k, kcap, sign=sign, block_rows=br, chunk=chunk,
-            interpret=cfg._interpret())
+        return _split_rows(functools.partial(
+            _topk.topk_compact, k=k, kcap=kcap, sign=sign, block_rows=br,
+            chunk=chunk, interpret=cfg._interpret()), rows)
     from repro.kernels.ref import topk_compact_ref
     return topk_compact_ref(rows.astype(jnp.float32), k, kcap, sign=sign)
 

@@ -22,10 +22,14 @@ Quantized pages: when the pool dtype is int8 the per-page scale
 vectors (``[n_pages, page_size]`` f32 — one scale per token slot, the
 wire format mirroring ``qdq_gemm``'s per-row scale) ride along through
 the same table-indexed gather and the dequantize multiply is fused
-into the attention dot's VMEM residency.  Note the int8 native tile on
-real TPUs is (32, 128); the smoke geometries here (page_size 8-16,
-hd 32) validate in interpret mode — production TPU pools want
-page_size ≥ 32.
+into the attention dot's VMEM residency.
+
+TPU tiling: the kernel sees the pool as ``[n_pages, page_size, KV*hd]``
+(a free reshape), so one block is a ``(page_size, hd)`` slab — hd lanes,
+page_size sublanes.  Mosaic accepts it when hd is a multiple of 128 and
+page_size a multiple of 8 (bf16: 16, int8: 32); smaller smoke
+geometries run only in interpret mode.  Scales ride as ``[n_pages,
+page_size, 1]`` columns, the lane-broadcast the dequantize needs.
 
 Masking needs no position bookkeeping in the pool: pages are dense in
 logical token order, so slot ``t`` of logical page ``j`` holds global
@@ -74,32 +78,33 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, *rest, nblk: int, pb: int,
     q = q_ref[0, 0].astype(jnp.float32) * scale          # [G, hd]
     length = len_ref[b]
     for i in range(pb):
-        k = k_refs[i][0, :, 0, :].astype(jnp.float32)    # [ps, hd]
-        v = v_refs[i][0, :, 0, :].astype(jnp.float32)
+        k = k_refs[i][0].astype(jnp.float32)             # [ps, hd]
+        v = v_refs[i][0].astype(jnp.float32)
         if quant:
-            k = k * ks_refs[i][0, :][:, None]
-            v = v * vs_refs[i][0, :][:, None]
-        kpos = (j * pb + i) * ps + jax.lax.iota(jnp.int32, ps)
-        live = kpos < length
+            k = k * ks_refs[i][0]                        # [ps, 1] scales
+            v = v * vs_refs[i][0]
+        kpos = (j * pb + i) * ps + jax.lax.broadcasted_iota(
+            jnp.int32, (1, ps), 1)
+        live = kpos < length                             # [1, ps]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [G, ps]
-        s = jnp.where(live[None, :], s, NEG_INF)
-        m_prev = m_acc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        s = jnp.where(live, s, NEG_INF)
+        m_prev = m_acc[...]                              # [G, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         # explicit mask on p (not just on s): with every slot dead the
         # m subtraction would otherwise turn NEG_INF scores into
         # exp(0) = 1 and a free engine slot would emit garbage mass
-        p = jnp.where(live[None, :], jnp.exp(s - m_new[:, None]), 0.0)
+        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
         alpha = jnp.exp(m_prev - m_new)
-        l_acc[...] = l_acc[...] * alpha + jnp.sum(p, axis=1)
-        o_acc[...] = o_acc[...] * alpha[:, None] + jax.lax.dot_general(
+        l_acc[...] = l_acc[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        o_acc[...] = o_acc[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_acc[...] = m_new
 
     @pl.when(j == nblk - 1)
     def _finish():
-        o = o_acc[...] / jnp.maximum(l_acc[...], 1e-30)[:, None]
+        o = o_acc[...] / jnp.maximum(l_acc[...], 1e-30)
         o_ref[0, 0] = o.astype(o_ref.dtype)
 
 
@@ -130,16 +135,21 @@ def paged_decode_fwd(q, kp, vp, kscale, vscale, tables, lengths, *,
     nblk = (P + pad) // pb
     LAUNCHES["paged_decode"] += 1
     q4 = q.reshape(B, 1, KV, G, hd)[:, 0]                # [B, KV, G, hd]
+    # head h of a page is lanes [h*hd, (h+1)*hd) of the flattened view
+    kp = kp.reshape(n_pages, ps, KV * hd)
+    vp = vp.reshape(n_pages, ps, KV * hd)
+    kscale = kscale.reshape(n_pages, ps, 1)
+    vscale = vscale.reshape(n_pages, ps, 1)
 
     def page_spec(i):
         return pl.BlockSpec(
-            (1, ps, 1, hd),
-            lambda b, h, j, tbl, lens, i=i: (tbl[b, j * pb + i], 0, h, 0))
+            (1, ps, hd),
+            lambda b, h, j, tbl, lens, i=i: (tbl[b, j * pb + i], 0, h))
 
     def scale_spec(i):
         return pl.BlockSpec(
-            (1, ps),
-            lambda b, h, j, tbl, lens, i=i: (tbl[b, j * pb + i], 0))
+            (1, ps, 1),
+            lambda b, h, j, tbl, lens, i=i: (tbl[b, j * pb + i], 0, 0))
 
     in_specs = [pl.BlockSpec((1, 1, G, hd),
                              lambda b, h, j, tbl, lens: (b, h, 0, 0))]
@@ -161,8 +171,8 @@ def paged_decode_fwd(q, kp, vp, kscale, vscale, tables, lengths, *,
                                lambda b, h, j, tbl, lens: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, hd), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
-            pltpu.VMEM((G,), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
         ],
     )
     kern = functools.partial(_paged_kernel, nblk=nblk, pb=pb, ps=ps,

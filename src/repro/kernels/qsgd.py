@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.launch_stats import LAUNCHES
+from repro.kernels.topk_compress import COMPILER_PARAMS
 
 
 def _kernel(x_ref, u_ref, o_ref, *, s: int):
@@ -53,6 +54,7 @@ def qsgd_quantize(x: jax.Array, u: jax.Array, s: int, *,
         ],
         out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(x, u)
     return out[:rows]

@@ -75,9 +75,8 @@ def topk_compact_ref(acc: jnp.ndarray, k: int, kcap: int, *,
     memory instead of crossing the wire.
 
     Deliberately sort- and scatter-free (prefix-sum slots + chunked
-    one-hot contraction): this is also the *fallback* compact path
-    inside 0.4.x partial-manual shard_map regions, where ``lax.top_k``
-    and scatters crash the SPMD partitioner (DESIGN.md §4.1).
+    one-hot contraction), like the kernel: this is also the compact
+    path for rows the kernel does not take.
 
     Returns (idx [rows, kcap] int32, val [rows, kcap] f32,
     new_mem [rows, n] f32, cnt [rows] int32).

@@ -54,13 +54,15 @@ def _pad_dim(x: jnp.ndarray, axis: int, multiple: int,
 
 
 def _sparse_kernel(x_ref, idx_ref, val_ref, o_ref, *, chunk: int):
-    """One (block_m, block_rows) output tile.
+    """One (block_rows, block_m) tile of the *transposed* output.
 
     x_ref: [block_m, n] activations; idx_ref/val_ref: [block_rows, kcap]
-    survivor buffers; o_ref: [block_m, block_rows].  The weight tile is
-    decoded chunk-by-chunk with a one-hot contraction (MXU-friendly, no
-    scatter) and immediately contracted against the matching activation
-    columns; sentinel slots (val = 0) contribute nothing.
+    survivor buffers; o_ref: [block_rows, block_m] (weight rows on the
+    sublanes, so an 8-row weight tile is a legal TPU block at any decode
+    batch).  The weight tile is decoded chunk-by-chunk with a one-hot
+    contraction (MXU-friendly, no scatter) and immediately contracted
+    against the matching activation columns; sentinel slots (val = 0)
+    contribute nothing.
     """
     n = x_ref.shape[1]
     bm = x_ref.shape[0]
@@ -69,21 +71,21 @@ def _sparse_kernel(x_ref, idx_ref, val_ref, o_ref, *, chunk: int):
     val = val_ref[...].astype(jnp.float32)
 
     def body(j, acc):
-        base = j * chunk
+        base = pl.multiple_of(j * chunk, chunk)
         cols = base + jax.lax.broadcasted_iota(jnp.int32, (1, 1, chunk), 2)
         # [br, kcap, chunk] one-hot of each survivor against this chunk
         oh = (idx[:, :, None] == cols).astype(jnp.float32)
         # decode the (br, chunk) weight tile: w[r, c] = sum_s val[r,s]*oh
-        w = jax.lax.dot_general(
-            val, oh, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        xc = x_ref[:, pl.dslice(base, chunk)].astype(jnp.float32)
+        # ([r, 1, s] @ [r, s, c], a batched matmul per weight row)
+        w = jnp.einsum("rqs,rsc->rqc", val[:, None, :], oh,
+                       preferred_element_type=jnp.float32)[:, 0, :]
+        xc = x_ref[:, pl.ds(base, chunk)].astype(jnp.float32)
         return acc + jax.lax.dot_general(
-            xc, w, (((1,), (1,)), ((), ())),
+            w, xc, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     acc = jax.lax.fori_loop(0, n // chunk, body,
-                            jnp.zeros((bm, br), jnp.float32))
+                            jnp.zeros((br, bm), jnp.float32))
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -121,12 +123,12 @@ def sparse_gemm(x: jnp.ndarray, idx: jnp.ndarray, val: jnp.ndarray,
             pl.BlockSpec((br, kcap), lambda m, r: (r, 0)),
             pl.BlockSpec((br, kcap), lambda m, r: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, br), lambda m, r: (m, r)),
-        out_shape=jax.ShapeDtypeStruct((xp.shape[0], idxp.shape[0]),
+        out_specs=pl.BlockSpec((br, bm), lambda m, r: (r, m)),
+        out_shape=jax.ShapeDtypeStruct((idxp.shape[0], xp.shape[0]),
                                        jnp.float32),
         interpret=interpret,
     )(xp, idxp, valp)
-    return out[:M, :R]
+    return out[:R, :M].T
 
 
 # ---------------------------------------------------------------------------
@@ -135,11 +137,12 @@ def sparse_gemm(x: jnp.ndarray, idx: jnp.ndarray, val: jnp.ndarray,
 
 
 def _qdq_kernel(x_ref, lv_ref, scale_ref, o_ref):
-    """One (block_m, block_rows) output tile: dequantize the integer
-    weight tile in VMEM (levels * per-row scale) and contract."""
+    """One (block_rows, block_m) tile of the transposed output:
+    dequantize the integer weight tile in VMEM (levels * per-row scale)
+    and contract."""
     w = lv_ref[...].astype(jnp.float32) * scale_ref[...].astype(jnp.float32)
     o_ref[...] = jax.lax.dot_general(
-        x_ref[...].astype(jnp.float32), w, (((1,), (1,)), ((), ())),
+        w, x_ref[...].astype(jnp.float32), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
@@ -172,9 +175,9 @@ def qdq_gemm(x: jnp.ndarray, levels: jnp.ndarray, scale: jnp.ndarray,
             pl.BlockSpec((br, n), lambda m, r: (r, 0)),
             pl.BlockSpec((br, 1), lambda m, r: (r, 0)),
         ],
-        out_specs=pl.BlockSpec((bm, br), lambda m, r: (m, r)),
-        out_shape=jax.ShapeDtypeStruct((xp.shape[0], lvp.shape[0]),
+        out_specs=pl.BlockSpec((br, bm), lambda m, r: (r, m)),
+        out_shape=jax.ShapeDtypeStruct((lvp.shape[0], xp.shape[0]),
                                        jnp.float32),
         interpret=interpret,
     )(xp, lvp, scp)
-    return out[:M, :R]
+    return out[:R, :M].T

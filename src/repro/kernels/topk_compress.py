@@ -19,8 +19,7 @@ Two emission modes share the threshold search:
     each survivor its output slot; a chunked one-hot matmul performs
     the slot scatter on the MXU — TPUs have no vector scatter).  This
     is the wire form of ``aggregate="sparse_allgather"`` and is sort-
-    free, so it also partitions under the 0.4.x SPMD partitioner where
-    ``lax.top_k`` hard-crashes (DESIGN.md §3.3, §4.1).
+    free (DESIGN.md §3.3).
 
 Grid: one program per row-block.  Block shape (ROWS, n) where n is the
 row length (the shard-local compression row, typically 1-8k) — (8, 512)
@@ -34,8 +33,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.launch_stats import LAUNCHES
+
+#: scoped VMEM the compression kernels may use (v5e has 128 MiB of
+#: VMEM; the compiler's default scope is 16 MiB).  At block_rows 8 the
+#: Top_k, compact and QSGD kernels compile for v5e up to 2^17-lane rows
+#: within it — ``DispatchConfig.max_row``.
+VMEM_LIMIT_BYTES = 64 << 20
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def _bisect_threshold(a: jnp.ndarray, k: int, iters: int) -> jnp.ndarray:
@@ -80,7 +87,7 @@ def _kernel(acc_ref, sel_ref, mem_ref, cnt_ref, *, k: int, iters: int,
         sel = jnp.where(mask, jnp.sign(acc) * norm / denom, 0.0)
     sel_ref[...] = sel.astype(sel_ref.dtype)
     mem_ref[...] = (acc - sel).astype(mem_ref.dtype)
-    cnt_ref[...] = cnt.astype(jnp.int32)
+    cnt_ref[...] = cnt[:, None].astype(jnp.int32)
 
 
 def topk_compress(acc: jax.Array, k: int, *, iters: int = 24,
@@ -88,9 +95,9 @@ def topk_compress(acc: jax.Array, k: int, *, iters: int = 24,
                   interpret: bool = False):
     """acc: [rows, n] -> (selected [rows, n], new_mem [rows, n], cnt [rows]).
 
-    VMEM per program: 3 blocks of (block_rows, n) f32 — for n = 8192 and
-    block_rows = 8 that is ~0.8 MB, comfortably inside the ~16 MB VMEM
-    budget with double buffering.
+    VMEM per program: 3 blocks of (block_rows, n) f32, double-buffered,
+    plus the kernel's temporaries — inside ``VMEM_LIMIT_BYTES`` up to
+    n = 2^17 at block_rows = 8 (``DispatchConfig.max_row``).
     """
     LAUNCHES["topk_compress"] += 1
     rows, n = acc.shape
@@ -107,15 +114,17 @@ def topk_compress(acc: jax.Array, k: int, *, iters: int = 24,
         out_specs=[
             pl.BlockSpec((br, n), lambda i: (i, 0)),
             pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br,), lambda i: (i,)),
+            pl.BlockSpec((br, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(acc.shape, jnp.float32),
             jax.ShapeDtypeStruct(acc.shape, jnp.float32),
-            jax.ShapeDtypeStruct((acc.shape[0],), jnp.int32),
+            jax.ShapeDtypeStruct((acc.shape[0], 1), jnp.int32),
         ],
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(acc)
+    cnt = cnt[:, 0]
     if pad:
         sel, mem, cnt = sel[:rows], mem[:rows], cnt[:rows]
     return sel, mem, cnt
@@ -126,8 +135,21 @@ def topk_compress(acc: jax.Array, k: int, *, iters: int = 24,
 # ---------------------------------------------------------------------------
 
 
-def _compact_kernel(acc_ref, idx_ref, val_ref, mem_ref, cnt_ref, *, k: int,
-                    kcap: int, iters: int, sign: bool, chunk: int):
+def _prefix_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sum along the lanes (axis 1): log2(n) shifted
+    adds over lane rotations — Mosaic lowers no ``cumsum``."""
+    n = x.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    shift = 1
+    while shift < n:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, 1), 0)
+        shift *= 2
+    return x
+
+
+def _compact_kernel(acc_ref, idx_ref, val_ref, mem_ref, cnt_ref, pos_scr,
+                    sel_scr, *, k: int, kcap: int, iters: int, sign: bool,
+                    chunk: int):
     acc = acc_ref[...].astype(jnp.float32)        # [ROWS, N]
     rows, n = acc.shape
     a = jnp.abs(acc)
@@ -137,7 +159,7 @@ def _compact_kernel(acc_ref, idx_ref, val_ref, mem_ref, cnt_ref, *, k: int,
     # survivors strictly before it in the row.  Survivors past the
     # buffer capacity (only possible under heavy ties) are dropped from
     # the wire; the fused memory update below absorbs them.
-    pos = jnp.cumsum(mask.astype(jnp.int32), axis=1) - 1
+    pos = _prefix_sum(mask.astype(jnp.int32)) - 1
     emit = mask & (pos < kcap)
     cnt = jnp.sum(emit.astype(jnp.int32), axis=1)
     sel = jnp.where(emit, acc, 0.0)
@@ -146,27 +168,34 @@ def _compact_kernel(acc_ref, idx_ref, val_ref, mem_ref, cnt_ref, *, k: int,
         denom = jnp.maximum(cnt[:, None].astype(jnp.float32), 1.0)
         sel = jnp.where(emit, jnp.sign(acc) * norm / denom, 0.0)
     mem_ref[...] = (acc - sel).astype(mem_ref.dtype)
-    cnt_ref[...] = cnt.astype(jnp.int32)
+    cnt_ref[...] = cnt[:, None].astype(jnp.int32)
     # slot scatter as a chunked one-hot matmul: TPUs have no vector
     # scatter, but onehot[r, c, j] = [pos == j & emit] contracted
     # against the values (and against the global indices) on the MXU
     # writes every chunk's survivors to their slots.  f32 holds indices
-    # exactly up to 2^24 >> max_row.
+    # exactly up to 2^24 >> max_row.  The chunks are read back from
+    # VMEM scratch (a ref slices at a traced offset, a value does not);
+    # slot -1 marks a coordinate that does not cross the wire.
+    pos_scr[...] = jnp.where(emit, pos, -1)
+    sel_scr[...] = sel
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, 1, kcap), 2)
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
 
     def body(g, carry):
         idx_acc, val_acc = carry
-        p = jax.lax.dynamic_slice(pos, (0, g * chunk), (rows, chunk))
-        e = jax.lax.dynamic_slice(emit, (0, g * chunk), (rows, chunk))
-        v = jax.lax.dynamic_slice(sel, (0, g * chunk), (rows, chunk))
-        oh = ((p[:, :, None] == cols) & e[:, :, None]).astype(jnp.float32)
+        base = pl.multiple_of(g * chunk, chunk)
+        p = pos_scr[:, pl.ds(base, chunk)]
+        v = sel_scr[:, pl.ds(base, chunk)]
+        oh = (p[:, :, None] == cols).astype(jnp.float32)
         gidx = jnp.broadcast_to((g * chunk + lane).astype(jnp.float32),
                                 (rows, chunk))
+        # [r, 1, c] @ [r, c, j]: a batched matmul per compression row
         val_acc = val_acc + jnp.einsum(
-            "rc,rcj->rj", v, oh, preferred_element_type=jnp.float32)
+            "rqc,rcj->rqj", v[:, None, :], oh,
+            preferred_element_type=jnp.float32)[:, 0, :]
         idx_acc = idx_acc + jnp.einsum(
-            "rc,rcj->rj", gidx, oh, preferred_element_type=jnp.float32)
+            "rqc,rcj->rqj", gidx[:, None, :], oh,
+            preferred_element_type=jnp.float32)[:, 0, :]
         return idx_acc, val_acc
 
     zeros = jnp.zeros((rows, kcap), jnp.float32)
@@ -204,6 +233,7 @@ def topk_compact(acc: jax.Array, k: int, kcap: int, *, iters: int = 24,
     grid = (acc.shape[0] // br,)
     kern = functools.partial(_compact_kernel, k=k, kcap=kcap, iters=iters,
                              sign=sign, chunk=chunk)
+    scratch = [pltpu.VMEM((br, n), jnp.int32), pltpu.VMEM((br, n), jnp.float32)]
     idx, val, mem, cnt = pl.pallas_call(
         kern,
         grid=grid,
@@ -212,16 +242,19 @@ def topk_compact(acc: jax.Array, k: int, kcap: int, *, iters: int = 24,
             pl.BlockSpec((br, kcap), lambda i: (i, 0)),
             pl.BlockSpec((br, kcap), lambda i: (i, 0)),
             pl.BlockSpec((br, n), lambda i: (i, 0)),
-            pl.BlockSpec((br,), lambda i: (i,)),
+            pl.BlockSpec((br, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((acc.shape[0], kcap), jnp.int32),
             jax.ShapeDtypeStruct((acc.shape[0], kcap), jnp.float32),
             jax.ShapeDtypeStruct(acc.shape, jnp.float32),
-            jax.ShapeDtypeStruct((acc.shape[0],), jnp.int32),
+            jax.ShapeDtypeStruct((acc.shape[0], 1), jnp.int32),
         ],
+        scratch_shapes=scratch,
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(acc)
+    cnt = cnt[:, 0]
     if pad:
         idx, val, mem, cnt = idx[:rows], val[:rows], mem[:rows], cnt[:rows]
     return idx, val, mem, cnt

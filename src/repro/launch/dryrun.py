@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import set_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import (
@@ -217,7 +216,7 @@ def lower_train(cfg, mesh, *, zero1: bool = False, compressor_mode: str = "topk"
     key_sds = _sds((2,), jnp.uint32, mesh, P())
     results = {}
     for name, fn in (("sync_step", sync_step), ("local_step", local_step)):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             # donate the state: steady-state training aliases the Qsparse
             # state buffers in place (alias_bytes in memory_analysis)
             lowered = jax.jit(fn, donate_argnums=(0,)).lower(
@@ -242,7 +241,7 @@ def lower_serve(cfg, mesh, shape_name: str):
             return model.prefill(params, batch, cfg, policy,
                                  max_len=sh.seq_len)
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             results["prefill"] = jax.jit(prefill_fn).lower(params_sds, batch_sds)
         return results
     # decode: one new token against a seq_len cache
@@ -257,7 +256,7 @@ def lower_serve(cfg, mesh, shape_name: str):
         return model.decode_step(params, cache, token, sh.seq_len - 1, cfg,
                                  policy)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         results["decode"] = jax.jit(decode_fn).lower(
             params_sds, cache_sharded, token_sds)
     return results
@@ -314,8 +313,6 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
             compiled = low.compile()
             mem = compiled.memory_analysis()
             ca = compiled.cost_analysis() or {}
-            if isinstance(ca, (list, tuple)):  # 0.4.x: list of one dict
-                ca = ca[0] if ca else {}
             coll = collective_bytes(compiled.as_text())
             record["steps"][name] = {
                 "compile_s": round(time.time() - t1, 1),

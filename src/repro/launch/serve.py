@@ -14,6 +14,10 @@ interleave, slot reuse, per-request metrics.
       --steps 4 --ckpt /tmp/ck
   PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --smoke \
       --checkpoint /tmp/ck --compressed --scheduler continuous
+
+``--layers N`` serves the published widths at depth N (the depth the
+checkpoint was trained at).  ``main(argv)`` also runs in-process and
+returns the run's summary.
 """
 
 from __future__ import annotations
@@ -24,8 +28,10 @@ import json
 import jax
 import numpy as np
 
-from repro.configs import ARCHS, get_config
+from repro.configs import ARCHS
 from repro.configs.policies import get_policy_preset
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.train import resolve_config
 from repro.models import get_model
 from repro.serve import compressed as sc
 from repro.serve.engine import ServeEngine
@@ -73,12 +79,17 @@ def load_params(args, cfg, model):
     return params, src
 
 
-def main(argv=None):
+def main(argv=None) -> dict:
+    """Serve; returns the run's summary (requests finished, tokens,
+    the densify count and the page pool's statistics)."""
     ap = argparse.ArgumentParser(
         description="serve a (compressed) checkpoint with continuous "
                     "batching")
     ap.add_argument("--arch", choices=sorted(ARCHS), required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="published widths at this depth (not with "
+                         "--smoke)")
     ap.add_argument("--checkpoint", default=None,
                     help="dense (train --ckpt) or compact "
                          "(save_compact) checkpoint directory")
@@ -124,7 +135,8 @@ def main(argv=None):
                     help="write the metrics summary to this file")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch, smoke=args.smoke)
+    cfg = resolve_config(args.arch, args.smoke, args.layers)
+    print(f"compile cache: {enable_compile_cache()}", flush=True)
     if args.flash:
         import dataclasses
         cfg = dataclasses.replace(cfg, use_pallas=True)
@@ -139,7 +151,7 @@ def main(argv=None):
     params, source = load_params(args, cfg, model)
     sc.reset_stats()
     sizes = sc.tree_bytes(params)
-    print(f"arch={args.arch} source: {source}")
+    print(f"arch={args.arch} layers={cfg.n_layers} source: {source}")
     print(f"resident params: {sizes['compressed'] / 1e6:.2f} MB "
           f"(dense equivalent {sizes['dense'] / 1e6:.2f} MB, "
           f"{sizes['leaves']} leaves)")
@@ -184,19 +196,22 @@ def main(argv=None):
     if args.compressed and sc.STATS["densify"]:
         raise SystemExit("zero-densify violated: the serving path "
                          f"densified {sc.STATS['densify']} leaves")
+    payload = {
+        "requests_per_s": res["requests_per_s"],
+        "tokens_per_s": res["tokens_per_s"],
+        "steps": res["steps"],
+        "densify": sc.STATS["densify"],
+        "finished": len(mets),
+        "new_tokens": [m.new_tokens for m in mets],
+    }
+    if args.paged:
+        payload["pool"] = res["pool"]
     if args.json:
         with open(args.json, "w") as f:
-            payload = {
-                "requests_per_s": res["requests_per_s"],
-                "tokens_per_s": res["tokens_per_s"],
-                "steps": res["steps"],
-                "densify": sc.STATS["densify"],
-            }
-            if args.paged:
-                payload["pool"] = res["pool"]
             json.dump(payload, f, indent=2)
     sample = res["outputs"].get(0, [])[:10]
     print("sample:", sample)
+    return payload
 
 
 if __name__ == "__main__":

@@ -1,37 +1,36 @@
 """Production training launcher.
 
 Drives the distributed Qsparse-local-SGD engine (core/distributed.py)
-for any assigned architecture on a jax mesh.  On real TPU hardware this
-is the per-host entry point (jax.distributed handles multi-host); on
-this CPU container it runs with forced host devices for integration
-testing:
+for any assigned architecture on a jax mesh.  On TPU hardware this is
+the per-host entry point; on a CPU it runs with forced host devices:
 
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
   PYTHONPATH=src python -m repro.launch.train --arch yi-6b --smoke \
-      --mesh 8x1 --steps 20 --H 4
+      --mesh 4x2 --steps 20 --H 4
 
-On 0.4.x jax use a TP=1 mesh (e.g. 8x1): a >1 tensor-parallel auto
-axis cannot partition the scanned layer stacks inside the partial-
-manual region there (see repro/compat.py).  Modern jax takes any mesh.
+``--layers N`` keeps an architecture's published widths and cuts its
+depth to N layers (one chip's share; ``chip_smoke.py`` runs yi-6b so).
+``main(argv)`` also runs in-process and returns the run's summary.
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import set_mesh
 from repro.configs import ARCHS, get_config, get_policy_preset
 from repro.core import policy as pol
 from repro.core.distributed import (ShardCompressor, make_dist_round,
                                     make_dist_steps)
 from repro.core.engine import stack_block
 from repro.data import LMTokenStream
-from repro.launch.mesh import data_axes, worker_count
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import data_axes, parse_mesh, worker_count
 from repro.models import get_model
 from repro.optim import momentum_sgd, warmup_piecewise
 from repro.sharding.specs import activation_policy, param_specs, sanitize_spec
@@ -86,11 +85,31 @@ def resolve_policy_arg(args) -> pol.ChannelSpec:
     return pol.ChannelSpec(up, down)
 
 
-def main():
+def resolve_config(arch: str, smoke: bool, layers):
+    """The run's ModelConfig: the smoke config, or the published one —
+    with its depth cut to ``layers`` when given (widths untouched)."""
+    if layers is not None and smoke:
+        raise SystemExit("--layers cuts the published config's depth; "
+                         "it does not apply to --smoke")
+    cfg = get_config(arch, smoke=smoke)
+    if layers is not None:
+        if layers < 1:
+            raise SystemExit(f"--layers must be >= 1, got {layers}")
+        cfg = dataclasses.replace(cfg, n_layers=layers)
+    return cfg
+
+
+def main(argv=None) -> dict:
+    """Train; returns the per-step losses and uplink wire bits, the
+    kernel launch counts and where the state lives (see the end of this
+    function)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), required=True)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced config (CPU-sized)")
+    ap.add_argument("--layers", type=int, default=None,
+                    help="published widths at this depth (not with "
+                         "--smoke)")
     ap.add_argument("--mesh", default="4x2",
                     help="DxM or PxDxM device mesh, e.g. 16x16 or 2x16x16")
     ap.add_argument("--steps", type=int, default=20)
@@ -137,8 +156,7 @@ def main():
                          "compiles each sync round (H local steps + "
                          "sync) into one scanned, donated program; "
                          "'step' keeps per-step dispatch.  Identical "
-                         "trajectories; 0.4.x TP>1 meshes auto-fall "
-                         "back to per-step with a warning")
+                         "trajectories")
     ap.add_argument("--overlap", action="store_true",
                     help="overlapped round driver (DESIGN.md §10): "
                          "dispatch windows of consecutive equal-length "
@@ -187,14 +205,17 @@ def main():
     ap.add_argument("--zero1", action="store_true")
     ap.add_argument("--lr", type=float, default=0.1)
     ap.add_argument("--ckpt", default=None)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    dims = [int(x) for x in args.mesh.split("x")]
-    names = ("pod", "data", "model")[-len(dims):]
-    mesh = jax.make_mesh(tuple(dims), names)
+    cfg = resolve_config(args.arch, args.smoke, args.layers)
+    print(f"compile cache: {enable_compile_cache()}", flush=True)
+    mesh = parse_mesh(args.mesh)
     daxes = data_axes(mesh)
     R = worker_count(mesh)
-    cfg = get_config(args.arch, smoke=args.smoke)
+    print(f"arch={args.arch} layers={cfg.n_layers} d={cfg.d_model} "
+          f"heads={cfg.n_heads}/{cfg.n_kv_heads} ff={cfg.d_ff} "
+          f"V={cfg.vocab} {cfg.param_dtype} mesh={dict(mesh.shape)}",
+          flush=True)
     model = get_model(cfg)
     policy = activation_policy(cfg, for_serving=False, data_axes=daxes)
     specs = param_specs(cfg)
@@ -288,29 +309,24 @@ def main():
                         aggregate=args.aggregate, wire=args.wire,
                         staleness_weight=args.staleness_weight)
         if args.runtime == "round":
-            init_fn, round_fn, fused = make_dist_fault_round(
+            init_fn, round_fn = make_dist_fault_round(
                 *engine_args, **fault_kw)
-            print(f"runtime: fault round "
-                  f"({'fused' if fused else 'per-step fallback'})",
-                  flush=True)
+            print("runtime: fault round", flush=True)
         else:
             init_fn, local_step, sync_step = make_dist_fault_steps(
                 *engine_args, **fault_kw)
     elif args.runtime == "round" and args.overlap:
         from repro.core.distributed import make_dist_multiround
-        init_fn, multi_fn, fused = make_dist_multiround(
-            *engine_args, **engine_kw)
-        print(f"runtime: round overlap "
-              f"({'fused' if fused else 'per-round fallback'}), "
-              f"window {args.overlap_window}", flush=True)
-    elif args.runtime == "round":
-        init_fn, round_fn, fused = make_dist_round(*engine_args, **engine_kw)
-        print(f"runtime: round ({'fused' if fused else 'per-step fallback'})",
+        init_fn, multi_fn = make_dist_multiround(*engine_args, **engine_kw)
+        print(f"runtime: round overlap, window {args.overlap_window}",
               flush=True)
+    elif args.runtime == "round":
+        init_fn, round_fn = make_dist_round(*engine_args, **engine_kw)
+        print("runtime: round", flush=True)
     else:
         init_fn, local_step, sync_step = make_dist_steps(*engine_args,
                                                          **engine_kw)
-    from jax.sharding import NamedSharding
+    from jax.sharding import NamedSharding, PartitionSpec as P
     put_specs = jax.tree_util.tree_map(
         lambda leaf, sp: NamedSharding(
             mesh, sanitize_spec(sp, leaf.shape, mesh)),
@@ -329,170 +345,182 @@ def main():
     def launch_note_once():
         return " ".join(f"{k}={v}" for k, v in LAUNCHES.items() if v) or "none"
 
+    history = []  # (step, loss, uplink bits) as logged
+    first_s = None  # wall time to the first result (compiles included)
+
     def log_step(t, kind, loss, up, down, note=""):
+        nonlocal first_s
+        if first_s is None:
+            first_s = time.time() - t0
+        history.append((t + 1, loss, up))
         print(f"step {t + 1:4d} [{kind}] loss {loss:.4f} "
               f"bits up {up:.3g} down {down:.3g}{note}", flush=True)
 
-    with set_mesh(mesh):
-        params = jax.device_put(params, put_specs)
-        state = init_fn(params)
-        stream = LMTokenStream(vocab=cfg.vocab, R=R, order=64, seed=0)
-        key = jax.random.PRNGKey(1)
-        t0 = time.time()
-        # kernel launches are counted at trace time (launch_stats.py):
-        # snapshot after the first sync step traces — with megabuffer
-        # packing this shows one launch per operator family per
-        # direction per sync round, regardless of leaf count
-        reset_launches()
-        launch_note = None
+    # no jax.set_mesh context: the engine's shard_maps name their mesh,
+    # and the Pallas compressors split rows over the 'model' axis in a
+    # nested shard_map that a concrete context mesh would break on TPU
+    # (kernels/dispatch.py _split_rows)
+    params = jax.device_put(params, put_specs)
+    state = init_fn(params)
+    stream = LMTokenStream(vocab=cfg.vocab, R=R, order=64, seed=0)
+    # committed like the key the round program hands back, so the
+    # second round does not compile the program again
+    key = jax.device_put(jax.random.PRNGKey(1), NamedSharding(mesh, P()))
+    t0 = time.time()
+    # kernel launches are counted at trace time (launch_stats.py):
+    # snapshot after the first sync step traces — with megabuffer
+    # packing this shows one launch per operator family per
+    # direction per sync round, regardless of leaf count
+    reset_launches()
+    launch_note = None
 
-        def is_sync_step(t):
-            """Scenario runs sync where any worker's mask row fires; the
-            fixed schedule keeps the historical every-H + final step.
-            Fault runs close at *event* steps — any scheduled sync row
-            or any queued-payload arrival (scenarios.fault_replay)."""
-            if fault_events is not None:
-                return bool(fault_events[t])
-            if scenario_mask is not None:
-                return bool(scenario_mask[t].any())
-            return (t + 1) % args.H == 0 or t == args.steps - 1
+    def is_sync_step(t):
+        """Scenario runs sync where any worker's mask row fires; the
+        fixed schedule keeps the historical every-H + final step.
+        Fault runs close at *event* steps — any scheduled sync row
+        or any queued-payload arrival (scenarios.fault_replay)."""
+        if fault_events is not None:
+            return bool(fault_events[t])
+        if scenario_mask is not None:
+            return bool(scenario_mask[t].any())
+        return (t + 1) % args.H == 0 or t == args.steps - 1
 
-        if args.runtime == "round" and args.overlap:
-            # overlapped round runtime (DESIGN.md §10): windows of
-            # consecutive equal-length rounds run as ONE scanned
-            # multi-round program — the sync collective of round w
-            # pipelines against round w+1's local compute.  Same key
-            # threading as the per-round loop below, so trajectories
-            # match; wire-bit logging coarsens to window granularity
-            # (interior tail steps show the pre-window totals).
-            from repro.core import rounds as rnd_mod
-            plans, s0 = [], 0
-            for t in range(args.steps):
-                if is_sync_step(t) or t == args.steps - 1:
-                    tail = (scenario_mask[t] if scenario_mask is not None
-                            else np.asarray(is_sync_step(t)))
-                    plans.append(rnd_mod.RoundPlan(
-                        s0, t - s0 + 1, np.asarray(tail)))
-                    s0 = t + 1
-            windows = rnd_mod.window_rounds(
-                plans, max_window=args.overlap_window)
-            batch_iter = stream.batches(args.batch, args.seq, args.steps,
-                                        seed=1)
-            mirror = key
-            for win in windows:
-                W, L = len(win), win[0].length
-                pending = []
-                for _ in range(W * L):
-                    mirror, sub = jax.random.split(mirror)
-                    pending.append(make_batch(next(batch_iter), sub))
-                blocks = jax.tree_util.tree_map(
-                    lambda x: x.reshape((W, L) + x.shape[1:]),
-                    stack_block(pending))
-                prev_up = float(state.bits)
-                prev_down = float(state.bits_down)
-                if scenario_mask is not None:
-                    masks_arr = jnp.asarray(
-                        np.stack([np.asarray(p.mask) for p in win]))
-                    state, losses, key = multi_fn(state, blocks,
-                                                  masks_arr, key)
-                else:
-                    state, losses, key = multi_fn(state, blocks, key)
-                mirror = key
-                if launch_note is None:
-                    launch_note = launch_note_once()
-                losses = np.asarray(losses)
-                for wi, plan in enumerate(win):
-                    for i in range(L):
-                        tail = i == L - 1
-                        final = tail and wi == W - 1
-                        last_loss = float(losses[wi, i])
-                        log_step(
-                            plan.start + i,
-                            "sync " if tail and is_sync_step(plan.stop - 1)
-                            else "local",
-                            last_loss,
-                            float(state.bits) if final else prev_up,
-                            float(state.bits_down) if final else prev_down,
-                            f" launches/round [{launch_note}]"
-                            if final else "")
-        elif args.runtime == "round":
-            # round runtime (DESIGN.md §7): accumulate steps until the
-            # schedule's next sync, run the block as one program.  The
-            # round program splits the PRNG key in-program with the
-            # same per-step sequence this host mirror uses for batch
-            # construction, so trajectories match --runtime step.
-            pending, block_start, mirror = [], 0, key
-            for t, batch in enumerate(
-                    stream.batches(args.batch, args.seq, args.steps,
-                                   seed=1)):
+    if args.runtime == "round" and args.overlap:
+        # overlapped round runtime (DESIGN.md §10): windows of
+        # consecutive equal-length rounds run as ONE scanned
+        # multi-round program — the sync collective of round w
+        # pipelines against round w+1's local compute.  Same key
+        # threading as the per-round loop below, so trajectories
+        # match; wire-bit logging coarsens to window granularity
+        # (interior tail steps show the pre-window totals).
+        from repro.core import rounds as rnd_mod
+        plans, s0 = [], 0
+        for t in range(args.steps):
+            if is_sync_step(t) or t == args.steps - 1:
+                tail = (scenario_mask[t] if scenario_mask is not None
+                        else np.asarray(is_sync_step(t)))
+                plans.append(rnd_mod.RoundPlan(
+                    s0, t - s0 + 1, np.asarray(tail)))
+                s0 = t + 1
+        windows = rnd_mod.window_rounds(
+            plans, max_window=args.overlap_window)
+        batch_iter = stream.batches(args.batch, args.seq, args.steps,
+                                    seed=1)
+        mirror = key
+        for win in windows:
+            W, L = len(win), win[0].length
+            pending = []
+            for _ in range(W * L):
                 mirror, sub = jax.random.split(mirror)
-                pending.append(make_batch(batch, sub))
-                # scenario runs close rounds at any-worker-sync steps
-                # (an all-False final flush is legal: the masked tail
-                # sync is exactly a local step on every worker)
-                if not (is_sync_step(t) or t == args.steps - 1):
-                    continue
-                block = stack_block(pending)
-                prev_up, prev_down = float(state.bits), float(state.bits_down)
+                pending.append(make_batch(next(batch_iter), sub))
+            blocks = jax.tree_util.tree_map(
+                lambda x: x.reshape((W, L) + x.shape[1:]),
+                stack_block(pending))
+            prev_up = float(state.bits)
+            prev_down = float(state.bits_down)
+            if scenario_mask is not None:
+                masks_arr = jnp.asarray(
+                    np.stack([np.asarray(p.mask) for p in win]))
+                state, losses, key = multi_fn(state, blocks,
+                                              masks_arr, key)
+            else:
+                state, losses, key = multi_fn(state, blocks, key)
+            mirror = key
+            if launch_note is None:
+                launch_note = launch_note_once()
+            losses = np.asarray(losses)
+            for wi, plan in enumerate(win):
+                for i in range(L):
+                    tail = i == L - 1
+                    final = tail and wi == W - 1
+                    last_loss = float(losses[wi, i])
+                    log_step(
+                        plan.start + i,
+                        "sync " if tail and is_sync_step(plan.stop - 1)
+                        else "local",
+                        last_loss,
+                        float(state.bits) if final else prev_up,
+                        float(state.bits_down) if final else prev_down,
+                        f" launches/round [{launch_note}]"
+                        if final else "")
+    elif args.runtime == "round":
+        # round runtime (DESIGN.md §7): accumulate steps until the
+        # schedule's next sync, run the block as one program.  The
+        # round program splits the PRNG key in-program with the
+        # same per-step sequence this host mirror uses for batch
+        # construction, so trajectories match --runtime step.
+        pending, block_start, mirror = [], 0, key
+        for t, batch in enumerate(
+                stream.batches(args.batch, args.seq, args.steps,
+                               seed=1)):
+            mirror, sub = jax.random.split(mirror)
+            pending.append(make_batch(batch, sub))
+            # scenario runs close rounds at any-worker-sync steps
+            # (an all-False final flush is legal: the masked tail
+            # sync is exactly a local step on every worker)
+            if not (is_sync_step(t) or t == args.steps - 1):
+                continue
+            block = stack_block(pending)
+            prev_up, prev_down = float(state.bits), float(state.bits_down)
+            if fault_rows is not None:
+                from repro.core.engine import index_rows
+                rblock = index_rows(fault_rows,
+                                    slice(block_start, t + 1))
+                state, losses, key = round_fn(state, block, rblock, key)
+            elif scenario_mask is not None:
+                state, losses, key = round_fn(
+                    state, block, jnp.asarray(scenario_mask[t]), key)
+            else:
+                state, losses, key = round_fn(state, block, key)
+            mirror = key
+            if launch_note is None:
+                launch_note = launch_note_once()
+            losses = np.asarray(losses)
+            for i in range(len(pending)):
+                tail = i == len(pending) - 1
+                last_loss = float(losses[i])
+                log_step(
+                    block_start + i,
+                    "sync " if tail and is_sync_step(t) else "local",
+                    last_loss,
+                    float(state.bits) if tail else prev_up,
+                    float(state.bits_down) if tail else prev_down,
+                    f" launches/round [{launch_note}]" if tail else "")
+            pending, block_start = [], t + 1
+    else:
+        ls, ss = jax.jit(local_step), jax.jit(sync_step)
+        for t, batch in enumerate(
+                stream.batches(args.batch, args.seq, args.steps,
+                               seed=1)):
+            key, sub = jax.random.split(key)
+            b = make_batch(batch, sub)
+            if is_sync_step(t):
                 if fault_rows is not None:
                     from repro.core.engine import index_rows
-                    rblock = index_rows(fault_rows,
-                                        slice(block_start, t + 1))
-                    state, losses, key = round_fn(state, block, rblock, key)
+                    state, loss = ss(state, b, index_rows(fault_rows, t),
+                                     sub)
                 elif scenario_mask is not None:
-                    state, losses, key = round_fn(
-                        state, block, jnp.asarray(scenario_mask[t]), key)
+                    state, loss = ss(state, b, sub,
+                                     jnp.asarray(scenario_mask[t]))
                 else:
-                    state, losses, key = round_fn(state, block, key)
-                mirror = key
+                    state, loss = ss(state, b, sub)
+                kind = "sync "
                 if launch_note is None:
                     launch_note = launch_note_once()
-                losses = np.asarray(losses)
-                for i in range(len(pending)):
-                    tail = i == len(pending) - 1
-                    last_loss = float(losses[i])
-                    log_step(
-                        block_start + i,
-                        "sync " if tail and is_sync_step(t) else "local",
-                        last_loss,
-                        float(state.bits) if tail else prev_up,
-                        float(state.bits_down) if tail else prev_down,
-                        f" launches/round [{launch_note}]" if tail else "")
-                pending, block_start = [], t + 1
-        else:
-            ls, ss = jax.jit(local_step), jax.jit(sync_step)
-            for t, batch in enumerate(
-                    stream.batches(args.batch, args.seq, args.steps,
-                                   seed=1)):
-                key, sub = jax.random.split(key)
-                b = make_batch(batch, sub)
-                if is_sync_step(t):
-                    if fault_rows is not None:
-                        from repro.core.engine import index_rows
-                        state, loss = ss(state, b, index_rows(fault_rows, t),
-                                         sub)
-                    elif scenario_mask is not None:
-                        state, loss = ss(state, b, sub,
-                                         jnp.asarray(scenario_mask[t]))
-                    else:
-                        state, loss = ss(state, b, sub)
-                    kind = "sync "
-                    if launch_note is None:
-                        launch_note = launch_note_once()
-                    note = f" launches/round [{launch_note}]"
+                note = f" launches/round [{launch_note}]"
+            else:
+                if fault_rows is not None:
+                    from repro.core.engine import index_rows
+                    state, loss = ls(state, b, index_rows(fault_rows, t),
+                                     sub)
                 else:
-                    if fault_rows is not None:
-                        from repro.core.engine import index_rows
-                        state, loss = ls(state, b, index_rows(fault_rows, t),
-                                         sub)
-                    else:
-                        state, loss = ls(state, b, sub)
-                    kind = "local"
-                    note = ""
-                last_loss = float(loss)
-                log_step(t, kind, last_loss, float(state.bits),
-                         float(state.bits_down), note)
-        dt = time.time() - t0
+                    state, loss = ls(state, b, sub)
+                kind = "local"
+                note = ""
+            last_loss = float(loss)
+            log_step(t, kind, last_loss, float(state.bits),
+                     float(state.bits_down), note)
+    dt = time.time() - t0
     total = float(state.bits) + float(state.bits_down)
     print(f"\n{args.steps} steps in {dt:.1f}s ({args.steps / dt:.2f} it/s); "
           f"R={R} workers, {int(state.rounds)} sync rounds, "
@@ -505,6 +533,25 @@ def main():
         checkpoint.save(args.ckpt, state.master, step=args.steps,
                         policy=channel_spec.to_dict())
         print("checkpoint saved to", args.ckpt)
+    # where the state lives: every device the state touches, and the
+    # devices holding the worker axis of the largest per-worker leaf
+    big = max(jax.tree_util.tree_leaves(state.local), key=lambda x: x.size)
+    return {
+        "steps": [h[0] for h in history],
+        "losses": [h[1] for h in history],
+        "bits_up": [h[2] for h in history],
+        "launches": dict(LAUNCHES),
+        "first_result_seconds": first_s,
+        "rounds": int(state.rounds),
+        "workers": R,
+        "layers": cfg.n_layers,
+        "state_devices": sorted({
+            d.id for leaf in jax.tree_util.tree_leaves(state)
+            for d in leaf.sharding.device_set}),
+        "worker_shard_devices": sorted(
+            sh.device.id for sh in big.addressable_shards),
+        "worker_shard_shape": tuple(big.sharding.shard_shape(big.shape)),
+    }
 
 
 if __name__ == "__main__":

@@ -21,25 +21,24 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import sharding_constraints_usable
 from repro.configs.base import ModelConfig, ShardingPolicy
 
 Array = jax.Array
 
 
 def maybe_shard(x: Array, spec: Optional[P]) -> Array:
-    if spec is None or not sharding_constraints_usable():
+    if spec is None:
         return x
     return jax.lax.with_sharding_constraint(x, spec)
 
 
 def pvary(x, axes):
     """Mark ``x`` as varying over the manual axes ``axes`` (vma typing
-    for scan carries created inside a shard_map region).  No-op on
-    0.4.x jax, whose shard_map has no vma typing to satisfy."""
-    if not axes or not hasattr(jax.lax, "pvary"):
+    for scan carries created inside a shard_map region)."""
+    if not axes:
         return x
-    return jax.tree_util.tree_map(lambda t: jax.lax.pvary(t, tuple(axes)), x)
+    return jax.tree_util.tree_map(
+        lambda t: jax.lax.pcast(t, tuple(axes), to="varying"), x)
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig, NO_SHARDING, ShardingPolicy
 from repro.models.layers import (
     attn_block_decode,
@@ -260,7 +259,7 @@ def _make_ep_apply(axis: str, E: int, C: int, nshards: int):
 
     def _fwd_mapped(x2d, eids, wts, w1, w3, w2, er):
         mesh = jax.sharding.get_abstract_mesh()
-        return shard_map(
+        return jax.shard_map(
             fwd_shard, mesh=mesh,
             in_specs=(P(), P(), P(), P(axis), P(axis), P(axis), P(axis)),
             out_specs=(P(), P()), axis_names={axis}, check_vma=True,
@@ -268,7 +267,7 @@ def _make_ep_apply(axis: str, E: int, C: int, nshards: int):
 
     def _bwd_mapped(x2d, eids, wts, w1, w3, w2, er, dout):
         mesh = jax.sharding.get_abstract_mesh()
-        return shard_map(
+        return jax.shard_map(
             bwd_shard, mesh=mesh,
             in_specs=(P(), P(), P(), P(axis), P(axis), P(axis), P(axis),
                       P()),

@@ -9,12 +9,12 @@ pytestmark = pytest.mark.slow
 DIST_MATCHES_REFERENCE = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import make_dist_steps, ShardCompressor
 from repro.core import qsparse, operators as ops, schedule
 from repro.optim import sgd, constant
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 R, d_in, d_out = 4, 16, 8
 params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
 specs = {"w": P(None, "model"), "b": P("model")}
@@ -51,7 +51,7 @@ state_ref = qsparse.init(params, inner, R)
 step_ref = jax.jit(qsparse.make_step(grad_fn, inner, op_ref, constant(0.1), R),
                    static_argnames=("sync",))
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     state = init_fn(params_dev)
     ls, ss = jax.jit(local_step), jax.jit(sync_step)
     key = jax.random.PRNGKey(1)
@@ -83,11 +83,11 @@ def test_dist_engine_matches_reference(subproc):
 ZERO1_EQUIV = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import make_dist_steps, ShardCompressor
 from repro.optim import sgd, constant
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 R, d_in, d_out = 4, 16, 8
 params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
 specs = {"w": P(None, "model"), "b": P("model")}
@@ -106,7 +106,7 @@ for zero1 in (False, True):
     init_fn, local_step, sync_step = make_dist_steps(
         grad_fn, sgd(), ShardCompressor("topk", 0.25), constant(0.1),
         mesh, ("data",), specs, zero1=zero1)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_fn(params)
         ls, ss = jax.jit(local_step), jax.jit(sync_step)
         key = jax.random.PRNGKey(1)
@@ -134,13 +134,13 @@ def test_zero1_equivalent(subproc):
 SPARSE_ALLGATHER = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import make_dist_steps, ShardCompressor
 from repro.optim import sgd, constant
 
-# TP=2 partial-manual mesh: the configuration whose sparse path used to
-# hard-crash the 0.4.x SPMD partitioner through lax.top_k.
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+# TP=2 partial-manual mesh: workers manual over 'data', 'model' left to
+# XLA's SPMD partitioner.
+mesh = make_mesh((4, 2), ("data", "model"))
 R, d_in, d_out = 4, 256, 16
 params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
 specs = {"w": P(None, "model"), "b": P("model")}
@@ -157,15 +157,10 @@ def grad_fn(p, batch):
 masters, bits = [], []
 for aggregate, disp in (("dense_psum", "reference"),
                         ("sparse_allgather", "kernel")):
-    # the dense baseline keeps reference dispatch: on 0.4.x a kernel
-    # output feeding an in-body pmean over an auto-axis-sharded operand
-    # trips IsManualSubgroup (ROADMAP open item); the sparse path's
-    # compact buffers leave the manual region via out_specs instead,
-    # so the kernel compact path does run inside this traced step.
     init_fn, local_step, sync_step = make_dist_steps(
         grad_fn, sgd(), ShardCompressor("topk", 0.05, dispatch=disp),
         constant(0.05), mesh, ("data",), specs, aggregate=aggregate)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_fn(params)
         key = jax.random.PRNGKey(1)
         kb, _ = jax.random.split(key)
@@ -173,9 +168,7 @@ for aggregate, disp in (("dense_psum", "reference"),
         y = jnp.einsum("rbi,io->rbo", x, Wtrue)
         lowered = jax.jit(sync_step).lower(state, (x, y), key).as_text()
         if aggregate == "sparse_allgather":
-            # acceptance: the kernel compact path is sort-free end to
-            # end; nothing in the traced sparse sync step needs the
-            # partitioner support 0.4.x lacks
+            # the kernel compact path is sort-free end to end
             assert "top_k" not in lowered, "lax.top_k leaked into sparse sync"
             assert "sort(" not in lowered, "sort leaked into sparse sync"
         ls, ss = jax.jit(local_step), jax.jit(sync_step)
@@ -198,8 +191,7 @@ print("SPARSE==DENSE OK", bits[0])
 
 def test_sparse_allgather_kernel_compact(subproc):
     """aggregate="sparse_allgather" runs through the compact kernel path
-    on this container (no lax.top_k in the traced step — sort-free even
-    inside the 0.4.x partial-manual region) and matches the dense-psum
+    (no lax.top_k in the traced step) and matches the dense-psum
     aggregation state-for-state and bit-for-bit."""
     out = subproc(SPARSE_ALLGATHER, devices=8)
     assert "SPARSE==DENSE OK" in out
@@ -208,13 +200,12 @@ def test_sparse_allgather_kernel_compact(subproc):
 DOWNLINK_PARITY = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import make_dist_steps, ShardCompressor
 from repro.optim import sgd, constant
 
-# legacy-0.4.x TP=2 partial-manual mesh: the downlink must stay
-# partition-safe here in BOTH aggregation modes (acceptance criterion)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+# TP=2 partial-manual mesh: the downlink runs in BOTH aggregation modes
+mesh = make_mesh((4, 2), ("data", "model"))
 R, d_in, d_out = 4, 256, 16
 params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
 specs = {"w": P(None, "model"), "b": P("model")}
@@ -235,7 +226,7 @@ def run(aggregate, disp, downlink, ddisp):
         grad_fn, sgd(), ShardCompressor("topk", 0.05, dispatch=disp),
         constant(0.05), mesh, ("data",), specs, aggregate=aggregate,
         downlink=dl)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_fn(params)
         ls, ss = jax.jit(ls_), jax.jit(ss_)
         key = jax.random.PRNGKey(1)
@@ -251,8 +242,7 @@ def run(aggregate, disp, downlink, ddisp):
 
 # compressed downlink: the dense-psum and sparse-allgather paths must
 # agree on worker state and BOTH directions' counted bits.  The sparse
-# leg runs the compact kernels for uplink and downlink alike (the
-# buffers leave the manual region via out_specs, 0.4.x-safe).
+# leg runs the compact kernels for uplink and downlink alike.
 sd = run("dense_psum", "reference", "topk", "reference")
 sp = run("sparse_allgather", "kernel", "topk", "kernel")
 np.testing.assert_allclose(np.asarray(sd.master["w"]),
@@ -287,112 +277,25 @@ print("DOWNLINK PARITY OK", float(sd.bits), float(sd.bits_down))
 
 def test_downlink_dense_sparse_parity(subproc):
     """Compressed downlink: dense-psum and sparse-allgather agree on
-    worker states and per-direction counted bits on the legacy 0.4.x
-    TP>1 partial-manual mesh (DESIGN.md §5)."""
+    worker states and per-direction counted bits on a TP>1
+    partial-manual mesh (DESIGN.md §5)."""
     out = subproc(DOWNLINK_PARITY, devices=8)
     assert "DOWNLINK PARITY OK" in out
-
-
-TP_KERNEL_GUARD = r"""
-import warnings
-import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import MODERN, set_mesh
-from repro.core.distributed import make_dist_steps, ShardCompressor
-from repro.optim import sgd, constant
-
-mesh = jax.make_mesh((4, 2), ("data", "model"))
-R, d_in, d_out = 4, 256, 16
-params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
-specs = {"w": P(None, "model"), "b": P("model")}
-params = jax.device_put(params, jax.tree.map(
-    lambda s: NamedSharding(mesh, s), specs,
-    is_leaf=lambda z: isinstance(z, P)))
-Wtrue = 0.3 * jax.random.normal(jax.random.PRNGKey(0), (d_in, d_out))
-
-def grad_fn(p, batch):
-    x, y = batch
-    f = lambda pp: jnp.mean((x @ pp["w"] + pp["b"] - y) ** 2)
-    return jax.value_and_grad(f)(p)
-
-def run(disp, ddisp=None):
-    dl = (None if ddisp is None
-          else ShardCompressor("topk", 0.1, dispatch=ddisp))
-    init_fn, ls_, ss_ = make_dist_steps(
-        grad_fn, sgd(), ShardCompressor("topk", 0.05, dispatch=disp),
-        constant(0.05), mesh, ("data",), specs, aggregate="dense_psum",
-        downlink=dl)
-    with set_mesh(mesh):
-        state = init_fn(params)
-        ls, ss = jax.jit(ls_), jax.jit(ss_)
-        key = jax.random.PRNGKey(1)
-        for t in range(8):
-            key, s1, s2 = jax.random.split(key, 3)
-            x = jax.random.normal(s1, (R, 8, d_in))
-            y = jnp.einsum("rbi,io->rbo", x, Wtrue)
-            if (t + 1) % 4 == 0:
-                state, loss = ss(state, (x, y), s2)
-            else:
-                state, loss = ls(state, (x, y), s2)
-    return state
-
-# ShardCompressor(dispatch="kernel") + dense psum on a TP>1 legacy mesh
-# used to hard-crash XLA (IsManualSubgroup, ROADMAP known issue); the
-# engine now auto-downgrades the uplink to reference dispatch with a
-# one-time warning and identical results.
-with warnings.catch_warnings(record=True) as wlog:
-    warnings.simplefilter("always")
-    s_kernel = run("kernel")
-    msgs = [str(w.message) for w in wlog]
-if MODERN:
-    assert not any("downgrading the uplink" in m for m in msgs), msgs
-else:
-    assert sum("downgrading the uplink" in m for m in msgs) == 1, msgs
-s_ref = run("reference")
-np.testing.assert_allclose(np.asarray(s_kernel.master["w"]),
-                           np.asarray(s_ref.master["w"]),
-                           rtol=1e-5, atol=1e-6)
-np.testing.assert_allclose(float(s_kernel.bits), float(s_ref.bits))
-
-# the *downlink* channel needs the same guard: its kernel launches also
-# trip IsManualSubgroup inside the dense-psum body, even though its
-# output never feeds a collective (reproduced before the guard)
-with warnings.catch_warnings(record=True) as wlog:
-    warnings.simplefilter("always")
-    s_dk = run("kernel", ddisp="kernel")
-    msgs = [str(w.message) for w in wlog]
-if not MODERN:
-    assert sum("downgrading the downlink" in m for m in msgs) == 1, msgs
-s_dr = run("reference", ddisp="reference")
-np.testing.assert_allclose(np.asarray(s_dk.master["w"]),
-                           np.asarray(s_dr.master["w"]),
-                           rtol=1e-5, atol=1e-6)
-np.testing.assert_allclose(float(s_dk.bits_down), float(s_dr.bits_down))
-print("TP KERNEL GUARD OK")
-"""
-
-
-def test_legacy_tp_kernel_guard(subproc):
-    """dispatch="kernel" + dense_psum on a TP>1 0.4.x mesh downgrades
-    to reference dispatch with one warning instead of crashing, and
-    matches the reference run exactly."""
-    out = subproc(TP_KERNEL_GUARD, devices=8)
-    assert "TP KERNEL GUARD OK" in out
 
 
 HETERO_POLICY = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import make_dist_steps, ShardCompressor
 from repro.core import policy as pol
 from repro.optim import sgd, constant
 
-# heterogeneous per-leaf policy (DESIGN.md §6) on the legacy TP=2
+# heterogeneous per-leaf policy (DESIGN.md §6) on a TP=2
 # partial-manual mesh: Top_k on the matmul, QSGD on the embedding,
 # dense on the bias — through BOTH aggregation paths, which must agree
 # on states and counted bits (acceptance criterion).
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 R, d_in, d_out, V = 4, 256, 16, 64
 params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,)),
           "embed": jnp.zeros((V, d_in))}
@@ -418,7 +321,7 @@ def run(aggregate, disp):
     init_fn, ls_, ss_ = make_dist_steps(
         grad_fn, sgd(), comp, constant(0.05), mesh, ("data",), specs,
         aggregate=aggregate)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_fn(params)
         ls, ss = jax.jit(ls_), jax.jit(ss_)
         key = jax.random.PRNGKey(1)
@@ -432,8 +335,8 @@ def run(aggregate, disp):
                 state, loss = ls(state, (x, y), s2)
     return state
 
-# the dense leg keeps reference dispatch (0.4.x TP>1 dense-psum kernel
-# guard); the sparse leg runs the compact kernels for the Top_k leaf
+# the dense leg runs the reference path, the sparse leg the compact
+# kernels for the Top_k leaf
 sd = run("dense_psum", "reference")
 sp = run("sparse_allgather", "kernel")
 for k in ("w", "b", "embed"):
@@ -450,8 +353,8 @@ print("HETERO POLICY PARITY OK", float(sd.bits))
 
 def test_hetero_policy_dense_sparse_parity(subproc):
     """A heterogeneous per-leaf policy (TopK + QSGD + identity) trains
-    through both distributed aggregation paths on the legacy 0.4.x
-    TP>1 mesh, with dense-psum and sparse-allgather agreeing on states
+    through both distributed aggregation paths on a TP>1 mesh, with
+    dense-psum and sparse-allgather agreeing on states
     and counted bits."""
     out = subproc(HETERO_POLICY, devices=8)
     assert "HETERO POLICY PARITY OK" in out
@@ -460,11 +363,11 @@ def test_hetero_policy_dense_sparse_parity(subproc):
 MULTIPOD = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import make_dist_steps, ShardCompressor
 from repro.optim import sgd, constant
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 R = 4  # pod * data
 d_in, d_out = 16, 8
 params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
@@ -482,7 +385,7 @@ def grad_fn(p, batch):
 init_fn, local_step, sync_step = make_dist_steps(
     grad_fn, sgd(), ShardCompressor("topk", 0.5), constant(0.1),
     mesh, ("pod", "data"), specs)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     state = init_fn(params)
     ls, ss = jax.jit(local_step), jax.jit(sync_step)
     key = jax.random.PRNGKey(1)
@@ -505,16 +408,14 @@ def test_multipod_axes(subproc):
 
 
 ROUND_PROGRAM_PARITY = r"""
-import warnings
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import (make_dist_round, make_dist_steps,
                                     ShardCompressor)
 from repro.optim import sgd, constant
 
-# TP=1 mesh: the fused scan-with-xs round program partitions on 0.4.x
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 R, d_in, d_out = 8, 16, 8
 params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
 specs = {"w": P(None, "model"), "b": P("model")}
@@ -543,7 +444,7 @@ for agg in ("dense_psum", "sparse_allgather"):
         init_fn, ls, ss = make_dist_steps(
             grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
             **common)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             st = init_fn(params)
             lsj, ssj = jax.jit(ls), jax.jit(ss)
             key = jax.random.PRNGKey(1)
@@ -554,11 +455,10 @@ for agg in ("dense_psum", "sparse_allgather"):
                 st, loss = step(st, bs[t], sub)
                 ref_losses.append(float(loss))
             ref = st
-        init_fn2, round_fn, fused = make_dist_round(
+        init_fn2, round_fn = make_dist_round(
             grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
             **common)
-        assert fused, "TP=1 legacy mesh must take the fused path"
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             st2 = init_fn2(params)
             key = jax.random.PRNGKey(1)
             losses2 = []
@@ -581,31 +481,23 @@ for agg in ("dense_psum", "sparse_allgather"):
                                       np.asarray(losses2))
         print("ROUND FUSED OK", agg, "downlink" if dl else "nodl")
 
-# TP>1 legacy mesh: make_dist_round must degrade to per-step with a
-# one-time warning, keeping identical trajectories
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+# TP>1 mesh: the same fused round program partitions over 'model' too
+mesh2 = make_mesh((4, 2), ("data", "model"))
 params2 = jax.device_put(
     {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))},
     jax.tree.map(lambda s: NamedSharding(mesh2, s), specs,
                  is_leaf=lambda z: isinstance(z, P)))
-with warnings.catch_warnings(record=True) as wlog:
-    warnings.simplefilter("always")
-    init_fn3, round_fn3, fused3 = make_dist_round(
-        grad_fn, sgd(), ShardCompressor("topk", 0.25), constant(0.1),
-        mesh2, ("data",), specs)
-from repro.compat import MODERN
-if not MODERN:
-    assert not fused3
-    assert any("fused round program" in str(w.message) for w in wlog), \
-        [str(w.message) for w in wlog]
-    bs2 = [(b[0][:4], b[1][:4]) for b in bs[:4]]
-    with set_mesh(mesh2):
-        st3 = init_fn3(params2)
-        block = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *bs2)
-        st3, larr, _ = round_fn3(st3, block, jax.random.PRNGKey(1))
-    assert np.all(np.isfinite(np.asarray(larr)))
-    assert int(st3.rounds) == 1
-print("ROUND FALLBACK OK")
+init_fn3, round_fn3 = make_dist_round(
+    grad_fn, sgd(), ShardCompressor("topk", 0.25), constant(0.1),
+    mesh2, ("data",), specs)
+bs2 = [(b[0][:4], b[1][:4]) for b in bs[:4]]
+with jax.set_mesh(mesh2):
+    st3 = init_fn3(params2)
+    block = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *bs2)
+    st3, larr, _ = round_fn3(st3, block, jax.random.PRNGKey(1))
+assert np.all(np.isfinite(np.asarray(larr)))
+assert int(st3.rounds) == 1
+print("ROUND TP2 OK")
 """
 
 
@@ -614,8 +506,7 @@ def test_round_program_parity(subproc):
     shard_mapped local step + sync at the tail, donated state) is
     bit-for-bit the per-step path on states and both direction ledgers
     for both aggregations, with and without a compressed downlink —
-    and degrades to per-step dispatch (one-time warning) on 0.4.x
-    TP>1 meshes."""
+    and runs on a TP>1 mesh too."""
     out = subproc(ROUND_PROGRAM_PARITY, devices=8, timeout=1500)
     assert out.count("ROUND FUSED OK") == 4
-    assert "ROUND FALLBACK OK" in out
+    assert "ROUND TP2 OK" in out

@@ -4,32 +4,14 @@ configs x 256/512 devices run via `python -m repro.launch.dryrun`)."""
 
 import pytest
 
-from repro import compat
-
 pytestmark = pytest.mark.slow
 
-# The legacy-jax skip is per-test, not module-wide: the sparse
-# aggregation path is sort-free since the compact kernels landed, so
-# its dry-run compiles run on 0.4.x too (test below).  Only the TP>1
-# production-matrix compiles stay modern-jax-only.
-TP_GT1_SKIP = pytest.mark.skipif(
-    not compat.MODERN,
-    reason="the TP>1 production-matrix train compiles scan layer stacks "
-           "inside a partial-manual shard_map with a >1 tensor-parallel "
-           "auto axis; 0.4.x XLA hard-crashes (CHECK IsManualSubgroup) "
-           "partitioning scan-with-xs there — unrelated to the sparse "
-           "wire path, which is sort-free since the compact kernels "
-           "(kernels/topk_compress.py) replaced lax.top_k and is "
-           "covered on 0.4.x below and in tests/test_distributed.py.  "
-           "TP=1 meshes are unaffected (see repro/compat.py).")
-
 CODE = r"""
-import os
 from repro.launch import dryrun as dr
-import jax
+from repro.launch.mesh import make_mesh
 from repro.configs.base import InputShape
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 shapes = {
     "train_4k": InputShape("train_4k", 64, 8, "train"),
     "prefill_32k": InputShape("prefill_32k", 64, 4, "prefill"),
@@ -61,18 +43,17 @@ print("DRYRUN SMOKE OK")
 """
 
 
-@TP_GT1_SKIP
 def test_dryrun_smoke_path(subproc):
     out = subproc(CODE, devices=8, timeout=1500)
     assert "DRYRUN SMOKE OK" in out
 
 
 CODE_SPARSE = r"""
-import jax
 from repro.launch import dryrun as dr
+from repro.launch.mesh import make_mesh
 from repro.configs.base import InputShape
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 shp = InputShape("train_4k", 64, 8, "train")
 rec = dr.run_one("yi-6b", "train_4k", smoke=True, mesh=mesh,
                  shape_override=shp, aggregate="sparse_allgather")
@@ -86,9 +67,8 @@ print("DRYRUN SPARSE OK")
 
 
 def test_dryrun_sparse_allgather(subproc):
-    """The sparse-allgather train compile runs on every supported jax —
-    the compact wire path traces without lax.top_k, so 0.4.x lowers and
-    compiles it (previously this whole module was legacy-skipped)."""
+    """The sparse-allgather train compile: the compact wire path traces
+    without lax.top_k."""
     out = subproc(CODE_SPARSE, devices=8, timeout=1500)
     assert "DRYRUN SPARSE OK" in out
 

@@ -498,7 +498,7 @@ def test_trainer_dead_fleet_records_empty_rounds(trainer_problem):
 DIST_COMMON = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import (make_dist_steps, make_dist_fault_steps,
                                     make_dist_fault_round, ShardCompressor)
 from repro.core import engine, scenarios as scn, rounds as rnd, \
@@ -506,7 +506,7 @@ from repro.core import engine, scenarios as scn, rounds as rnd, \
 from repro.core.engine import stack_block
 from repro.optim import sgd, constant
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 R, d_in, d_out = 8, 16, 8
 params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
 specs = {"w": P(), "b": P()}
@@ -538,7 +538,7 @@ def run_fault(wire, spec, sw="uniform"):
     init_fn, fls, fss = make_dist_fault_steps(
         grad_fn, inner, comp, constant(0.1), mesh, ("data",), specs,
         queue_depth=spec.depth, wire=wire, staleness_weight=sw)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_fn(params)
         jls, jss = jax.jit(fls), jax.jit(fss)
         key = jax.random.PRNGKey(1)
@@ -569,7 +569,7 @@ st = run_fault("dense_psum", scn.FaultSpec())
 init_fn, lsn, ssn = make_dist_steps(
     grad_fn, inner, comp, constant(0.1), mesh, ("data",), specs,
     partial=True)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     state = init_fn(params)
     jl, js = jax.jit(lsn), jax.jit(ssn)
     key = jax.random.PRNGKey(1)
@@ -598,22 +598,22 @@ DIST_FAULT_ROUNDS_AND_S2 = DIST_COMMON + r"""
 def run_fault_rounds(wire, spec):
     tables = spec.tables(T, R)
     rows = engine.fault_rows(mask, tables, R)
-    init_fn, round_fn, fused = make_dist_fault_round(
+    init_fn, round_fn = make_dist_fault_round(
         grad_fn, inner, comp, constant(0.1), mesh, ("data",), specs,
         queue_depth=spec.depth, wire=wire, staleness_weight="uniform")
     plans = rnd.compile_fault_rounds(mask, tables)
     bs = batches()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         state = init_fn(params)
         key = jax.random.PRNGKey(1)
         for p in plans:
             block = stack_block(bs[p.start:p.stop])
             rblock = engine.index_rows(rows, slice(p.start, p.stop))
             state, losses, key = round_fn(state, block, rblock, key)
-    return state, fused
+    return state
 
 for wire in ("dense_psum", "sparse_allgather"):
-    sr, fused = run_fault_rounds(wire, chaos)
+    sr = run_fault_rounds(wire, chaos)
     sp = run_fault(wire, chaos)
     np.testing.assert_array_equal(np.asarray(sr.master["w"]),
                                   np.asarray(sp.master["w"]))

@@ -259,12 +259,12 @@ def test_trainer_overlap_guards():
 DIST_MULTIROUND = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import (make_dist_round, make_dist_multiround,
                                     ShardCompressor)
 from repro.optim import sgd, constant
 
-mesh = jax.make_mesh((8, 1), ("data", "model"))
+mesh = make_mesh((8, 1), ("data", "model"))
 R, d_in, d_out = 8, 16, 8
 params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
 specs = {"w": P(None, "model"), "b": P("model")}
@@ -288,14 +288,13 @@ for _ in range(16):
 H, T = 4, 16
 comp = ShardCompressor("topk", 0.25)
 for dl in (None, ShardCompressor("topk", 0.5)):
-    init_fn, round_fn, fused = make_dist_round(
+    init_fn, round_fn = make_dist_round(
         grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
         downlink=dl)
-    init2, multi_fn, fused2 = make_dist_multiround(
+    init2, multi_fn = make_dist_multiround(
         grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
         downlink=dl)
-    assert fused and fused2
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = init_fn(params)
         key = jax.random.PRNGKey(1)
         ref_losses = []
@@ -320,15 +319,15 @@ for dl in (None, ShardCompressor("topk", 0.5)):
     print("DIST MULTIROUND OK", "downlink" if dl else "nodl")
 
 # partial=True: per-round tail masks stack to [W, R]
-init_fn, round_fn, _ = make_dist_round(
+init_fn, round_fn = make_dist_round(
     grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
     partial=True)
-init2, multi_fn, _ = make_dist_multiround(
+init2, multi_fn = make_dist_multiround(
     grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
     partial=True)
 rngm = np.random.default_rng(3)
 masks = jnp.asarray(rngm.random((T // H, R)) < 0.6)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     st = init_fn(params)
     key = jax.random.PRNGKey(1)
     ref_losses = []

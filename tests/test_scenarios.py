@@ -418,12 +418,12 @@ def test_dist_wire_parity_partial(subproc, aggregate):
 _DIST_CODE = """
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
-from repro.compat import set_mesh
+from repro.launch.mesh import make_mesh
 from repro.core.distributed import make_dist_steps, make_dist_round, \
     ShardCompressor
 from repro.optim import sgd, constant
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 R, d_in, d_out, T, H = 8, 12, 6, 12, 4
 params = {{"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}}
 specs = {{"w": P(None, None), "b": P(None)}}
@@ -454,7 +454,7 @@ def run(wire):
     init_fn, ls_, ss_ = make_dist_steps(
         grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
         wire=wire, aggregate="{aggregate}", partial=True)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         st = init_fn(params)
         ls, ss = jax.jit(ls_), jax.jit(ss_)
         k = jax.random.PRNGKey(1)
@@ -475,11 +475,10 @@ assert float(sd.bits) == float(sp.bits)
 assert int(sd.rounds) == int(sp.rounds) == T // H
 
 comp = ShardCompressor("topk", 0.25)
-init_fn, round_fn, fused = make_dist_round(
+init_fn, round_fn = make_dist_round(
     grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
     wire="dense_psum", aggregate="{aggregate}", partial=True)
-assert fused
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     st2 = init_fn(params)
     k = jax.random.PRNGKey(1)
     for r0 in range(0, T, H):
@@ -569,9 +568,10 @@ def test_fleet_scale_sharded_worker_axis(subproc):
     subproc("""
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import engine, operators as ops, scenarios as scn
+from repro.launch.mesh import make_mesh
 from repro.optim import constant, sgd
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 Rr, D, T, H = 1024, 16, 4, 2
 mask = scn.PRESETS["flaky_fleet"].mask(T, Rr, H=H)
 
