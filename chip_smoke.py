@@ -1,17 +1,23 @@
 """Smoke run of the main path on a TPU: yi-6b at its published widths.
 
-    python chip_smoke.py                # one chip: train, parity, serve
+    python chip_smoke.py                # one chip: kernels, train, parity, serve
     python chip_smoke.py --four-chips   # four chips: the data-parallel wires
 
-One chip (the default) runs three phases in this one process:
+One chip (the default) runs four phases in this one process:
 
+  (k) kernels — the Top_k kernels (dense and compact) against their jnp
+      oracles on one yi-6b-sized input: identical selections, counts,
+      memories and (idx, val) buffers, and the compact buffers decode
+      to the dense selection.
   (a) training — ``repro.launch.train.main`` on a 1x1 mesh: 8 steps of
       error-compensated Top_k (k = 1%, H = 4) with the Pallas kernels,
       writing a checkpoint.  Losses must be finite and the Top_k kernel
       must have launched.
   (a') parity — 2 steps, syncing every step, once with the kernels and
-      once with ``--dispatch reference``; the losses must agree within
-      one bf16 unit in the last place of the loss.
+      once with ``--dispatch reference``.  The two are separate compiled
+      programs whose bf16 gradients may round apart, so they are held
+      to bounds: losses within 1e-4 relative, first-sync wire bits
+      within 1 % (see ``phase_parity``).
   (b) serving — ``repro.launch.serve.main`` on that checkpoint,
       compressed (sparse-weight GEMMs), paged KV with the Pallas
       paged flash-decode, continuous batching: all 8 requests finish
@@ -20,13 +26,10 @@ One chip (the default) runs three phases in this one process:
 ``--four-chips`` runs only the paper's data-parallel path: a 4x1 mesh
 (four workers), the same model and steps, over the dense psum wire and
 then the compact sparse allgather wire.  The state must sit on all four
-chips.  Up to and including the first sync the two runs must agree:
-losses within one bf16 ulp, wire bits within 1e-4 relative.  They are
-not bit-identical on the chip: each wire is a different compiled round
-program, so even the local steps before any exchange round differently
-(a few 1e-5 in the loss), and the first sync's Top_k then sees inputs
-that differ at bf16 rounding.  After it the trajectories separate; the
-script prints by how much.
+chips.  The two wires are separate compiled programs whose local steps
+round apart on four chips, so they are held to measured bounds (see
+``compare_wires``): per-step losses and cumulative wire bits, and the
+final master parameters against the update training applied.
 
 The depth is cut to ``LAYERS`` (widths, heads, vocabulary untouched):
 compiled for v5e, the train round program at 2 layers needs 12.40 GiB
@@ -65,13 +68,6 @@ def train_args(layers: int, mesh: str, steps: int, H: int, *extra):
             "--H", str(H), "--policy", "topk:k=0.01", *extra]
 
 
-def bf16_tol(losses) -> float:
-    """One bf16 unit in the last place of the largest loss: the forward
-    runs in bf16, so two runs whose parameters agree up to rounding
-    may differ by that much."""
-    return 2.0 ** -8 * max(abs(x) for x in losses)
-
-
 def phase_train(train):
     import numpy as np
     ck = OUT / "ck"
@@ -91,24 +87,87 @@ def phase_train(train):
     return ck
 
 
+def phase_kernels():
+    """The Top_k kernels against their jnp oracles on one input, through
+    the dispatch entry points the engine calls: one [4096, 4096] leaf
+    (a yi-6b projection, rows of d_model, k = 1 %) holding an
+    error-compensated accumulator as training makes it, f32 memory plus
+    a bf16 step, so magnitudes tie.  Selections, counts, memories and
+    the compact (idx, val) buffers must be identical, and the compact
+    buffers must decode to the dense kernel's selection."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.operators import resolve_k
+    from repro.kernels import dispatch as dsp
+    from repro.kernels.ref import topk_compact_ref, topk_compress_ref
+    n = 4096
+    k = resolve_k(0.01, n)
+    kcap = dsp.capacity(k, n)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(0))
+    step = (1e-3 * jax.random.normal(k1, (n, n))).astype(jnp.bfloat16)
+    acc = 1e-4 * jax.random.normal(k2, (n, n)) + step.astype(jnp.float32)
+    cfg = dsp.DispatchConfig(mode="kernel")
+    kern = jax.jit(lambda a: dsp.topk_rows(a, k, cfg=cfg))(acc)
+    ref = jax.jit(lambda a: topk_compress_ref(a, k))(acc)
+    ckern = jax.jit(lambda a: dsp.compact_rows(a, k, kcap, cfg=cfg))(acc)
+    cref = jax.jit(lambda a: topk_compact_ref(a, k, kcap))(acc)
+    dec = jax.jit(lambda i, v: dsp.decode_rows(i, v, n))(ckern[0], ckern[1])
+    kern, ref, ckern, cref, dec = jax.device_get((kern, ref, ckern, cref, dec))
+    checks = {
+        "compress sel": np.array_equal(kern[0], ref[0]),
+        "compress mem": np.array_equal(kern[1], ref[1]),
+        "compress cnt": np.array_equal(kern[2], ref[2]),
+        "compact idx": np.array_equal(ckern[0], cref[0]),
+        "compact val": np.array_equal(ckern[1], cref[1]),
+        "compact mem": np.array_equal(ckern[2], cref[2]),
+        "compact cnt": np.array_equal(ckern[3], cref[3]),
+        "decode == compress sel": np.array_equal(dec, kern[0]),
+    }
+    log(f"[kernels] {n}x{n} k={k} kcap={kcap}: survivors kernel "
+        f"{int(kern[2].sum())} reference {int(ref[2].sum())} compact "
+        f"{int(ckern[3].sum())} (k x rows = {k * n}); identical: {checks}")
+    bad = [name for name, ok in checks.items() if not ok]
+    if bad:
+        raise SystemExit(f"kernels: kernel and oracle differ on {bad}")
+
+
+#: parity bounds between the kernel and the reference dispatch, which
+#: compile to different programs: their step-1 bf16 gradients may round
+#: apart, and where a row's k-th magnitude ties (bf16 steps tie often)
+#: the tie decides how many survive.  Measured on a v5e: losses 6.5e-7
+#: relative apart, bits 0.36 %.  A wrong selection or decode moves them
+#: much further (the compact decode's bf16 matmul bug moved the loss by
+#: 5e-3 to 6e-2 relative); with the same input the kernels are exact
+#: (phase_kernels).
+LOSS_RTOL = 1e-4
+BITS_RTOL = 1e-2
+
+
 def phase_parity(train):
     args = train_args(1, "1x1", 2, 1)
     kern = train.main(args + ["--dispatch", "auto"])
     ref = train.main(args + ["--dispatch", "reference"])
-    diff = max(abs(a - b) for a, b in zip(kern["losses"], ref["losses"]))
-    tol = bf16_tol(ref["losses"])
+    loss_rel = max(abs(a - b) / abs(b)
+                   for a, b in zip(kern["losses"], ref["losses"]))
+    bits_rel = abs(kern["bits_up"][0] - ref["bits_up"][0]) / ref["bits_up"][0]
     log(f"[parity] kernel losses={kern['losses']} reference losses="
-        f"{ref['losses']} max |diff|={diff} tol={tol}")
+        f"{ref['losses']} max relative diff={loss_rel} (bound {LOSS_RTOL})")
     log(f"[parity] kernel bits={kern['bits_up']} reference bits="
-        f"{ref['bits_up']} kernel launches={kern['launches']} reference "
+        f"{ref['bits_up']} step-1 relative diff={bits_rel} (bound "
+        f"{BITS_RTOL}); kernel launches={kern['launches']} reference "
         f"launches={ref['launches']}")
     if kern["launches"].get("topk_compress", 0) <= 0:
         raise SystemExit("parity: the kernel run launched no Top_k kernel")
     if any(ref["launches"].values()):
         raise SystemExit("parity: the reference run launched kernels")
-    if diff > tol:
+    if loss_rel > LOSS_RTOL:
         raise SystemExit(f"parity: kernel and reference losses differ by "
-                         f"{diff} > {tol}")
+                         f"{loss_rel} relative > {LOSS_RTOL}")
+    if bits_rel > BITS_RTOL:
+        raise SystemExit(f"parity: kernel and reference step-1 wire bits "
+                         f"differ by {bits_rel} relative > {BITS_RTOL}")
 
 
 def phase_serve(serve, ck):
@@ -142,7 +201,8 @@ def four_chips(train):
     runs = {}
     for wire in ("dense_psum", "sparse_allgather"):
         t = time.time()
-        res = runs[wire] = train.main(args + ["--wire", wire])
+        res = runs[wire] = train.main(args + ["--wire", wire],
+                                      return_master=True)
         log(f"[4x1 {wire}] seconds={time.time() - t:.1f} first_result_s="
             f"{res['first_result_seconds']:.1f} launches={res['launches']}")
         log(f"[4x1 {wire}] losses={res['losses']}")
@@ -159,23 +219,59 @@ def four_chips(train):
                 "topk_compress" if wire == "dense_psum" else "topk_compact",
                 0) <= 0:
             raise SystemExit(f"{wire}: no Top_k kernel launched")
-    dense, sparse = runs["dense_psum"], runs["sparse_allgather"]
-    H = 4  # the first sync is step H
-    diffs = [abs(a - b) for a, b in zip(dense["losses"], sparse["losses"])]
-    up_to_sync = max(diffs[:H])
-    bits_d, bits_s = dense["bits_up"][H - 1], sparse["bits_up"][H - 1]
-    bits_rel = abs(bits_d - bits_s) / bits_d
+    compare_wires(runs["dense_psum"], runs["sparse_allgather"])
+
+
+#: bounds between the two 4x1 wires.  They are separate compiled
+#: programs, and on four v5e chips their local steps already round
+#: apart before any exchange (step-2 losses 11.415734 vs 11.415724).
+#: Measured after the repair of the compact scatter (my chip run): bits
+#: 1.05e-6 relative apart, losses 1.7e-4 relative, final master 0.082 x
+#: the update apart.  A wrong sparse decode is far outside: before the
+#: repair the losses parted by 6e-2 relative after the first sync, and
+#: on one chip the master by 1.28 x the update.
+WIRE_BITS_RTOL = 1e-4
+WIRE_LOSS_RTOL = 1e-3
+WIRE_MASTER_GAP = 0.25
+
+
+def compare_wires(dense, sparse):
+    rel, max_diff, max_upd = update_gap(dense["master"], sparse["master"],
+                                        dense["master0"])
+    loss_rel = max(abs(a - b) / abs(a)
+                   for a, b in zip(dense["losses"], sparse["losses"]))
+    bits_rel = max(abs(a - b) / a
+                   for a, b in zip(dense["bits_up"], sparse["bits_up"]) if a)
     log(f"[4x1] identical: losses={dense['losses'] == sparse['losses']} "
-        f"bits={dense['bits_up'] == sparse['bits_up']}; through the first "
-        f"sync: max |loss diff|={up_to_sync} (tol "
-        f"{bf16_tol(dense['losses'][:H])}), bits {bits_d} vs {bits_s} "
-        f"(rel {bits_rel}); after it: max |loss diff|={max(diffs[H:])}")
-    if up_to_sync > bf16_tol(dense["losses"][:H]):
-        raise SystemExit(f"the two wires' losses differ by {up_to_sync} "
-                         f"before the first sync completed")
-    if bits_rel > 1e-4:
-        raise SystemExit(f"the two wires charged {bits_d} vs {bits_s} bits "
-                         f"at the first sync")
+        f"bits={dense['bits_up'] == sparse['bits_up']} master={max_diff == 0}"
+        f"; relative gaps: losses {loss_rel} (bound {WIRE_LOSS_RTOL}), "
+        f"bits {bits_rel} (bound {WIRE_BITS_RTOL}), master |dense - sparse|"
+        f" / |update| {rel} (bound {WIRE_MASTER_GAP}); max |master diff| "
+        f"{max_diff}, max |update| {max_upd}")
+    if bits_rel > WIRE_BITS_RTOL:
+        raise SystemExit(f"the two wires' bits differ by {bits_rel} relative")
+    if loss_rel > WIRE_LOSS_RTOL:
+        raise SystemExit(f"the two wires' losses differ by {loss_rel} "
+                         f"relative")
+    if rel > WIRE_MASTER_GAP:
+        raise SystemExit(f"the two wires' masters differ by {rel} x the "
+                         f"update")
+
+
+def update_gap(a, b, m0):
+    """How far two runs' final masters ``a`` and ``b`` part, against the
+    update ``a - m0`` that training applied: (||a - b|| / ||a - m0||
+    over all leaves, max |a - b|, max |a - m0|)."""
+    import jax
+    import numpy as np
+    num = den = max_diff = max_upd = 0.0
+    for x, y, z in zip(*(jax.tree_util.tree_leaves(t) for t in (a, b, m0))):
+        x, y, z = (np.asarray(v, np.float32) for v in (x, y, z))
+        num += float(np.square(x - y).sum())
+        den += float(np.square(x - z).sum())
+        max_diff = max(max_diff, float(np.abs(x - y).max()))
+        max_upd = max(max_upd, float(np.abs(x - z).max()))
+    return (num / den) ** 0.5, max_diff, max_upd
 
 
 def main(argv=None) -> None:
@@ -200,6 +296,7 @@ def main(argv=None) -> None:
         if args.four_chips:
             four_chips(train)
         else:
+            phase_kernels()
             ck = phase_train(train)
             phase_parity(train)
             phase_serve(serve, ck)

@@ -209,6 +209,9 @@ class ShardCompressor:
           (idx, val) survivor buffers plus the fused error memory
           directly (DESIGN.md §3.3), with the scatter-free jnp oracle
           as its transparent fallback.
+    row_axes: the mesh axes the engine's worker-manual region leaves
+          Auto (set by the engine builders, :func:`_bind_mesh`): the
+          row kernels split their rows over them.
     ops:  policy mode only — a ``CompressionOp`` tree (or single op) in
           the grads' structure, as produced by ``core.policy.resolve``.
           Per leaf: Top_k-family ops run the shard-local axis-Top_k
@@ -224,6 +227,7 @@ class ShardCompressor:
     k_frac: float = 0.01
     dispatch: str = "auto"
     ops: Any = None
+    row_axes: tuple = ()
 
     @classmethod
     def from_spec(cls, spec, params,
@@ -276,7 +280,13 @@ class ShardCompressor:
 
     def _dispatch_cfg(self):
         from repro.kernels.dispatch import DispatchConfig
-        return DispatchConfig(mode=self.dispatch)
+        return DispatchConfig(mode=self.dispatch, row_axes=self.row_axes)
+
+    def interprets_kernels(self) -> bool:
+        """True where the Top_k kernels would run in interpret mode
+        (off-TPU with ``dispatch="kernel"``)."""
+        cfg = self._dispatch_cfg()
+        return cfg.kernels_enabled() and cfg._interpret()
 
     def _plans(self, n_leaves: int):
         """Per-leaf execution plan: ("skip",), ("axis", k, sign_bits)
@@ -487,6 +497,23 @@ def state_shardings(mesh, data_axes: Sequence[str], param_specs, state_tree):
     return master, worker
 
 
+def _checks_vma(*comps: Optional[ShardCompressor]) -> bool:
+    """``check_vma`` for the engine's shard_map regions: on, except
+    around kernels run in interpret mode, whose loops the interpreter
+    replays as untyped jnp (compiled kernels type their outputs)."""
+    return not any(c is not None and c.interprets_kernels() for c in comps)
+
+
+def _bind_mesh(comp: Optional[ShardCompressor], mesh,
+               daxes: tuple) -> Optional[ShardCompressor]:
+    """``comp`` with the mesh axes the worker-manual region leaves Auto
+    (every non-worker axis) as its kernels' row-split axes."""
+    if comp is None:
+        return None
+    return dataclasses.replace(
+        comp, row_axes=tuple(a for a in mesh.axis_names if a not in daxes))
+
+
 def make_dist_steps(
     grad_fn: Callable,                 # (params, batch) -> (loss, grads)
     inner_opt: GradientTransform,
@@ -553,6 +580,9 @@ def make_dist_steps(
     daxes = tuple(data_axes)
     R = worker_count(mesh, daxes)
     manual = set(daxes)
+    compressor = _bind_mesh(compressor, mesh, daxes)
+    downlink = _bind_mesh(downlink, mesh, daxes)
+    check_vma = _checks_vma(compressor, downlink)
     up = chn.ShardChannel(compressor, "uplink")
     down = chn.ShardChannel(downlink, "downlink")
     down_active = not down.is_identity()
@@ -759,12 +789,7 @@ def make_dist_steps(
         """``extra_worker`` counts additional worker-sharded operands
         threaded between the core state and (step, batch, key): the
         downlink channel state (view, down_memory) and/or the per-step
-        sync mask of a partial-participation run.
-
-        Every engine region runs with ``check_vma=False``: the Pallas
-        compressors and their jnp oracles loop (``fori_loop``) over
-        carries that are traced without varying-axis types, and the
-        interpret-mode kernels cannot be typed at all."""
+        sync mask of a partial-participation run."""
         return jax.shard_map(
             body,
             mesh=mesh,
@@ -775,7 +800,7 @@ def make_dist_steps(
             ),
             out_specs=out_specs,
             axis_names=manual,
-            check_vma=False,
+            check_vma=check_vma,
         )
 
     def _bits_down_of(state):
@@ -1001,7 +1026,7 @@ def make_dist_steps(
                       worker_specs) + extra_specs + (P(), batch_spec, P()),
             out_specs=(worker_specs, worker_specs) + half_specs
             + ([P(tuple(daxes))] * n_arrays, P(), P()),
-            axis_names=manual, check_vma=False,
+            axis_names=manual, check_vma=check_vma,
         )
         out = mapped(
             state.master, state.local, state.memory, state.inner,
@@ -1140,7 +1165,7 @@ def make_dist_steps(
             in_specs=(P(), worker_specs, worker_specs)
             + mask_specs + (P(),),
             out_specs=(worker_specs, [P(tuple(daxes))] * n_down, P()),
-            axis_names=manual, check_vma=False,
+            axis_names=manual, check_vma=check_vma,
         )
         down_mem, darrays, down_bits = down_mapped(
             master_in, state.view, state.down_memory, *mask_in, key)
@@ -1210,7 +1235,7 @@ def make_dist_steps(
         mapped = jax.shard_map(
             body, mesh=mesh, in_specs=(P(),),
             out_specs=out_specs,
-            axis_names=manual, check_vma=False,
+            axis_names=manual, check_vma=check_vma,
         )
         # eager shard_map with auto (non-manual) axes is unimplemented;
         # under jit it lowers
@@ -1454,6 +1479,8 @@ def make_dist_fault_steps(
     daxes = tuple(data_axes)
     R = worker_count(mesh, daxes)
     manual = set(daxes)
+    compressor = _bind_mesh(compressor, mesh, daxes)
+    check_vma = _checks_vma(compressor)
     up = chn.ShardChannel(compressor, "uplink")
     down = chn.ShardChannel(None, "downlink")
     Dq = int(queue_depth)
@@ -1564,7 +1591,7 @@ def make_dist_fault_steps(
                       worker_specs) + (worker_specs,) * 5
             + (P(), batch_spec, P()),
             out_specs=(worker_specs,) * 4 + (P(),),
-            axis_names=manual, check_vma=False,
+            axis_names=manual, check_vma=check_vma,
         )
         local, memory, inner, view, loss = mapped(
             state.master, state.local, state.memory, state.inner,
@@ -1656,7 +1683,7 @@ def make_dist_fault_steps(
                       worker_specs) + (worker_specs,) * 5
             + (P(), batch_spec, P()),
             out_specs=(P(),) + (worker_specs,) * 8 + (P(), P()),
-            axis_names=manual, check_vma=False,
+            axis_names=manual, check_vma=check_vma,
         )
         (master, local, memory, inner, view, q, arrive, tau, arr_any,
          wire_bits, loss) = mapped(
@@ -1703,7 +1730,7 @@ def make_dist_fault_steps(
             + (P(), batch_spec, P()),
             out_specs=(worker_specs,) * 4
             + ([P(tuple(daxes))] * n_arrays, P(), P()),
-            axis_names=manual, check_vma=False,
+            axis_names=manual, check_vma=check_vma,
         )
         half_all, memory, inner, view, arrays, wire_bits, loss = mapped(
             state.master, state.local, state.memory, state.inner,
@@ -1849,7 +1876,7 @@ def make_dist_fault_steps(
         mapped = jax.shard_map(
             body, mesh=mesh, in_specs=(P(),),
             out_specs=(P(),) + (worker_specs,) * (4 + nq + 2),
-            axis_names=manual, check_vma=False,
+            axis_names=manual, check_vma=check_vma,
         )
         out = jax.jit(mapped)(params)
         master, local, memory, inner, view = out[:5]

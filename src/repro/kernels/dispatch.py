@@ -116,6 +116,9 @@ class DispatchConfig:
     pack: megabuffer-pack same-bucket leaves in compress_tree (one
           kernel launch per operator family per sync round)
     interpret: None — auto (interpret off-TPU); bool to force
+    row_axes: mesh axes the caller's ``shard_map`` region leaves Auto;
+          :func:`topk_rows` and :func:`compact_rows` split their rows
+          over them (``_split_rows``).  Empty outside a mesh region.
     """
 
     mode: str = "auto"
@@ -125,6 +128,7 @@ class DispatchConfig:
     block_rows: Optional[int] = None
     pack: bool = True
     interpret: Optional[bool] = None
+    row_axes: tuple = ()
 
     def __post_init__(self):
         if self.mode not in ("auto", "kernel", "reference"):
@@ -399,36 +403,39 @@ def compact_rows_eligible(row_len: int, kcap: int,
     return rows_eligible(row_len, cfg, leaf_size) and kcap <= cfg.max_cap
 
 
-def _split_rows(kernel: Callable, rows: jnp.ndarray):
-    """Run a row-independent Pallas kernel over ``rows`` [R, n] inside
-    the current mesh context.  A Mosaic call cannot be partitioned by
-    XLA, so where the context mesh still has Auto axes (the mesh
-    engine's 'model' axis inside its worker-manual region) the rows are
-    split over those axes by a nested ``shard_map`` — Corollary 1's
-    piecewise compression, shard by shard — zero-padded to a multiple
-    of their size.  Every output of ``kernel`` leads with the row axis.
+def _split_rows(kernel: Callable, rows: jnp.ndarray, axes: tuple, interpret):
+    """Run a row-independent Pallas kernel, ``kernel(rows,
+    interpret=...)``, over ``rows`` [R, n].
 
-    Trace such a program without a ``jax.set_mesh`` context (the mesh
-    engine's shard_maps name their mesh): under one, jax lowers the
-    nested region against the context's all-Auto device mesh, Mosaic
-    sees only the inner axes as manual, and the TPU compile is refused.
+    ``axes`` (``DispatchConfig.row_axes``) names the mesh axes that the
+    calling ``shard_map`` region leaves Auto: the mesh engine's 'model'
+    axis inside its worker-manual region.  XLA cannot partition a
+    Mosaic call, so the rows are split over those axes by a nested
+    ``shard_map`` on the calling region's mesh (Corollary 1's piecewise
+    compression, shard by shard), zero-padded to a multiple of their
+    size.  Every output of ``kernel`` leads with the row axis.
+
+    The TPU compiler refuses the nested region when the program is
+    traced under a ``jax.set_mesh`` context (jax then lowers it against
+    the context's all-Auto mesh), so the mesh engine is traced without
+    one: its shard_maps name their mesh.
     """
+    if not axes:
+        return kernel(rows, interpret=interpret)
     mesh = jax.sharding.get_abstract_mesh()
-    auto = () if mesh.empty else tuple(
-        a for a, t in zip(mesh.axis_names, mesh.axis_types)
-        if t == jax.sharding.AxisType.Auto)
-    if not auto:
-        return kernel(rows)
     n_shards = 1
-    for a in auto:
+    for a in axes:
         n_shards *= mesh.shape[a]
     r = rows.shape[0]
     pad = (-r) % n_shards
     if pad:
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
-    spec = jax.sharding.PartitionSpec(auto)
-    out = jax.shard_map(kernel, mesh=mesh, in_specs=spec, out_specs=spec,
-                        axis_names=set(auto), check_vma=False)(rows)
+    spec = jax.sharding.PartitionSpec(axes)
+    # interpret mode replays the kernel as untyped jnp: the engine then
+    # leaves its regions unchecked too (core/distributed._checks_vma)
+    out = jax.shard_map(functools.partial(kernel, interpret=interpret),
+                        mesh=mesh, in_specs=spec, out_specs=spec,
+                        axis_names=set(axes), check_vma=not interpret)(rows)
     return jax.tree_util.tree_map(lambda o: o[:r], out)
 
 
@@ -442,9 +449,9 @@ def topk_rows(rows: jnp.ndarray, k: int, *, sign: bool = False,
     cfg = _resolve(cfg)
     br = _block_rows(cfg, "topk_compress", rows.shape[0], rows.shape[1],
                      k, sign)
-    return _split_rows(functools.partial(
-        _topk.topk_compress, k=k, sign=sign, block_rows=br,
-        interpret=cfg._interpret()), rows)
+    kernel = functools.partial(_topk.topk_compress, k=k, sign=sign,
+                               block_rows=br)
+    return _split_rows(kernel, rows, cfg.row_axes, cfg._interpret())
 
 
 def compact_rows(rows: jnp.ndarray, k: int, kcap: int, *,
@@ -468,7 +475,7 @@ def compact_rows(rows: jnp.ndarray, k: int, kcap: int, *,
         br, chunk = _compact_geometry(cfg, rows.shape[0], n, k, sign)
         return _split_rows(functools.partial(
             _topk.topk_compact, k=k, kcap=kcap, sign=sign, block_rows=br,
-            chunk=chunk, interpret=cfg._interpret()), rows)
+            chunk=chunk), rows, cfg.row_axes, cfg._interpret())
     from repro.kernels.ref import topk_compact_ref
     return topk_compact_ref(rows.astype(jnp.float32), k, kcap, sign=sign)
 

@@ -11,6 +11,14 @@ import jax
 import jax.numpy as jnp
 
 
+def _vary_like(x: jnp.ndarray, like: jnp.ndarray) -> jnp.ndarray:
+    """``x`` typed as varying over the manual axes ``like`` varies over:
+    inside a ``shard_map`` a loop carry built from a constant must match
+    the type the loop body gives it."""
+    axes = tuple(jax.typeof(like).vma - jax.typeof(x).vma)
+    return jax.lax.pcast(x, axes, to="varying") if axes else x
+
+
 # ---------------------------------------------------------------------------
 # topk_compress: bisection-threshold top-k select + fused error update
 # ---------------------------------------------------------------------------
@@ -129,11 +137,14 @@ def topk_compact_ref(acc: jnp.ndarray, k: int, kcap: int, *,
         oh = ((p[:, :, None] == cols) & e[:, :, None]).astype(jnp.float32)
         gidx = jnp.broadcast_to((g * chunk + lane).astype(jnp.float32),
                                 (rows, chunk))
-        val_acc = val_acc + jnp.einsum("rc,rcj->rj", v, oh)
-        idx_acc = idx_acc + jnp.einsum("rc,rcj->rj", gidx, oh)
+        # exact f32 products: TPU's default matmul precision is bf16
+        val_acc = val_acc + jnp.einsum("rc,rcj->rj", v, oh,
+                                       precision=jax.lax.Precision.HIGHEST)
+        idx_acc = idx_acc + jnp.einsum("rc,rcj->rj", gidx, oh,
+                                       precision=jax.lax.Precision.HIGHEST)
         return idx_acc, val_acc
 
-    zeros = jnp.zeros((rows, kcap), jnp.float32)
+    zeros = _vary_like(jnp.zeros((rows, kcap), jnp.float32), acc)
     idx_acc, val_acc = jax.lax.fori_loop(0, (n + pad) // chunk, cbody,
                                          (zeros, zeros))
     slot = jnp.arange(kcap)[None, :]

@@ -43,6 +43,15 @@ from repro.kernels.launch_stats import LAUNCHES
 #: within it — ``DispatchConfig.max_row``.
 VMEM_LIMIT_BYTES = 64 << 20
 COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+#: matmul precision of the one-hot slot scatters: f32 operands exact
+EXACT = jax.lax.Precision.HIGHEST
+
+
+def out_struct(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
+    """A kernel output typed like its input ``like``: inside a
+    ``shard_map`` it varies over the same manual axes (row kernels are
+    row-local), which ``check_vma`` requires of a ``pallas_call``."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _bisect_threshold(a: jnp.ndarray, k: int, iters: int) -> jnp.ndarray:
@@ -117,9 +126,9 @@ def topk_compress(acc: jax.Array, k: int, *, iters: int = 24,
             pl.BlockSpec((br, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(acc.shape, jnp.float32),
-            jax.ShapeDtypeStruct(acc.shape, jnp.float32),
-            jax.ShapeDtypeStruct((acc.shape[0], 1), jnp.int32),
+            out_struct(acc.shape, jnp.float32, acc),
+            out_struct(acc.shape, jnp.float32, acc),
+            out_struct((acc.shape[0], 1), jnp.int32, acc),
         ],
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
@@ -173,7 +182,10 @@ def _compact_kernel(acc_ref, idx_ref, val_ref, mem_ref, cnt_ref, pos_scr,
     # scatter, but onehot[r, c, j] = [pos == j & emit] contracted
     # against the values (and against the global indices) on the MXU
     # writes every chunk's survivors to their slots.  f32 holds indices
-    # exactly up to 2^24 >> max_row.  The chunks are read back from
+    # exactly up to 2^24 >> max_row, and the HIGHEST-precision product
+    # keeps all 24 bits (the MXU's default rounds its operands to bf16:
+    # an index above 256 or an f32 value would come out wrong).  The
+    # chunks are read back from
     # VMEM scratch (a ref slices at a traced offset, a value does not);
     # slot -1 marks a coordinate that does not cross the wire.
     pos_scr[...] = jnp.where(emit, pos, -1)
@@ -191,10 +203,10 @@ def _compact_kernel(acc_ref, idx_ref, val_ref, mem_ref, cnt_ref, pos_scr,
                                 (rows, chunk))
         # [r, 1, c] @ [r, c, j]: a batched matmul per compression row
         val_acc = val_acc + jnp.einsum(
-            "rqc,rcj->rqj", v[:, None, :], oh,
+            "rqc,rcj->rqj", v[:, None, :], oh, precision=EXACT,
             preferred_element_type=jnp.float32)[:, 0, :]
         idx_acc = idx_acc + jnp.einsum(
-            "rqc,rcj->rqj", gidx[:, None, :], oh,
+            "rqc,rcj->rqj", gidx[:, None, :], oh, precision=EXACT,
             preferred_element_type=jnp.float32)[:, 0, :]
         return idx_acc, val_acc
 
@@ -245,10 +257,10 @@ def topk_compact(acc: jax.Array, k: int, kcap: int, *, iters: int = 24,
             pl.BlockSpec((br, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((acc.shape[0], kcap), jnp.int32),
-            jax.ShapeDtypeStruct((acc.shape[0], kcap), jnp.float32),
-            jax.ShapeDtypeStruct(acc.shape, jnp.float32),
-            jax.ShapeDtypeStruct((acc.shape[0], 1), jnp.int32),
+            out_struct((acc.shape[0], kcap), jnp.int32, acc),
+            out_struct((acc.shape[0], kcap), jnp.float32, acc),
+            out_struct(acc.shape, jnp.float32, acc),
+            out_struct((acc.shape[0], 1), jnp.int32, acc),
         ],
         scratch_shapes=scratch,
         compiler_params=COMPILER_PARAMS,

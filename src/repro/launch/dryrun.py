@@ -215,13 +215,15 @@ def lower_train(cfg, mesh, *, zero1: bool = False, compressor_mode: str = "topk"
     batch_sds = input_specs(cfg, _CUR_SHAPE[0], mesh, for_train=True)
     key_sds = _sds((2,), jnp.uint32, mesh, P())
     results = {}
+    # traced without a jax.set_mesh context, as the launcher runs it
+    # (kernels/dispatch.py _split_rows): the engine's shard_maps and the
+    # argument shardings name the mesh
     for name, fn in (("sync_step", sync_step), ("local_step", local_step)):
-        with jax.set_mesh(mesh):
-            # donate the state: steady-state training aliases the Qsparse
-            # state buffers in place (alias_bytes in memory_analysis)
-            lowered = jax.jit(fn, donate_argnums=(0,)).lower(
-                state_sharded, batch_sds, key_sds)
-            results[name] = lowered
+        # donate the state: steady-state training aliases the Qsparse
+        # state buffers in place (alias_bytes in memory_analysis)
+        lowered = jax.jit(fn, donate_argnums=(0,)).lower(
+            state_sharded, batch_sds, key_sds)
+        results[name] = lowered
     return results
 
 

@@ -99,10 +99,11 @@ def resolve_config(arch: str, smoke: bool, layers):
     return cfg
 
 
-def main(argv=None) -> dict:
+def main(argv=None, *, return_master: bool = False) -> dict:
     """Train; returns the per-step losses and uplink wire bits, the
     kernel launch counts and where the state lives (see the end of this
-    function)."""
+    function).  ``return_master`` adds host copies of the initial and
+    final master parameters (``master0``, ``master``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=sorted(ARCHS), required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -228,6 +229,7 @@ def main(argv=None) -> dict:
 
     # params first: the policy resolves per leaf against their paths
     params = model.init_params(jax.random.PRNGKey(0), cfg)
+    master0 = jax.device_get(params) if return_master else None
     channel_spec = resolve_policy_arg(args)
     print("policy:", channel_spec.to_string(), flush=True)
     if args.tune:
@@ -536,7 +538,7 @@ def main(argv=None) -> dict:
     # where the state lives: every device the state touches, and the
     # devices holding the worker axis of the largest per-worker leaf
     big = max(jax.tree_util.tree_leaves(state.local), key=lambda x: x.size)
-    return {
+    summary = {
         "steps": [h[0] for h in history],
         "losses": [h[1] for h in history],
         "bits_up": [h[2] for h in history],
@@ -552,6 +554,10 @@ def main(argv=None) -> dict:
             sh.device.id for sh in big.addressable_shards),
         "worker_shard_shape": tuple(big.sharding.shard_shape(big.shape)),
     }
+    if return_master:
+        summary["master0"] = master0
+        summary["master"] = jax.device_get(state.master)
+    return summary
 
 
 if __name__ == "__main__":

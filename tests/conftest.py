@@ -9,6 +9,7 @@ with an explicit reason instead of erroring at collection time.
 """
 
 import os
+import platform
 import subprocess
 import sys
 import types
@@ -17,6 +18,17 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src")
+
+# The parity tests compare two compiled programs of the same math bit for
+# bit.  On an x86 host with AVX-512, XLA's CPU backend vectorizes such
+# programs differently and their f32 results part in the last bits, so
+# the tests pin its code generation to AVX2, which every x86-64 host
+# this suite runs on has (jax is imported after this file).
+CPU_XLA_FLAGS = ("--xla_cpu_max_isa=AVX2"
+                 if platform.machine() in ("x86_64", "AMD64") else "")
+if CPU_XLA_FLAGS and CPU_XLA_FLAGS not in os.environ.get("XLA_FLAGS", ""):
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "") + " " + CPU_XLA_FLAGS).strip()
 
 try:
     import hypothesis  # noqa: F401
@@ -88,7 +100,8 @@ if HAVE_HYPOTHESIS:
 def run_subprocess(code: str, devices: int = 8, timeout: int = 900):
     """Run python code in a subprocess with a forced device count."""
     env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={devices} "
+                        f"{CPU_XLA_FLAGS}").strip()
     env["PYTHONPATH"] = SRC
     out = subprocess.run(
         [sys.executable, "-c", code], env=env, capture_output=True,

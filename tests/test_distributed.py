@@ -51,26 +51,25 @@ state_ref = qsparse.init(params, inner, R)
 step_ref = jax.jit(qsparse.make_step(grad_fn, inner, op_ref, constant(0.1), R),
                    static_argnames=("sync",))
 
-with jax.set_mesh(mesh):
-    state = init_fn(params_dev)
-    ls, ss = jax.jit(local_step), jax.jit(sync_step)
-    key = jax.random.PRNGKey(1)
-    H = 4
-    for t in range(32):
-        key, s1, s2 = jax.random.split(key, 3)
-        x = jax.random.normal(s1, (R, 16, d_in))
-        y = jnp.einsum("rbi,io->rbo", x, Wtrue)
-        sync = (t + 1) % H == 0
-        if sync:
-            state, loss = ss(state, (x, y), s2)
-        else:
-            state, loss = ls(state, (x, y), s2)
-        state_ref, loss_ref = step_ref(state_ref, (x, y), sync=sync, key=s2)
-        np.testing.assert_allclose(float(loss), float(loss_ref),
-                                   rtol=2e-5, atol=2e-5)
-    np.testing.assert_allclose(np.asarray(state.master["w"]),
-                               np.asarray(state_ref.master["w"]),
-                               rtol=1e-4, atol=1e-5)
+state = init_fn(params_dev)
+ls, ss = jax.jit(local_step), jax.jit(sync_step)
+key = jax.random.PRNGKey(1)
+H = 4
+for t in range(32):
+    key, s1, s2 = jax.random.split(key, 3)
+    x = jax.random.normal(s1, (R, 16, d_in))
+    y = jnp.einsum("rbi,io->rbo", x, Wtrue)
+    sync = (t + 1) % H == 0
+    if sync:
+        state, loss = ss(state, (x, y), s2)
+    else:
+        state, loss = ls(state, (x, y), s2)
+    state_ref, loss_ref = step_ref(state_ref, (x, y), sync=sync, key=s2)
+    np.testing.assert_allclose(float(loss), float(loss_ref),
+                               rtol=2e-5, atol=2e-5)
+np.testing.assert_allclose(np.asarray(state.master["w"]),
+                           np.asarray(state_ref.master["w"]),
+                           rtol=1e-4, atol=1e-5)
 print("DIST==REF OK")
 """
 
@@ -106,21 +105,20 @@ for zero1 in (False, True):
     init_fn, local_step, sync_step = make_dist_steps(
         grad_fn, sgd(), ShardCompressor("topk", 0.25), constant(0.1),
         mesh, ("data",), specs, zero1=zero1)
-    with jax.set_mesh(mesh):
-        state = init_fn(params)
-        ls, ss = jax.jit(local_step), jax.jit(sync_step)
-        key = jax.random.PRNGKey(1)
-        for t in range(16):
-            key, s1, s2 = jax.random.split(key, 3)
-            x = jax.random.normal(s1, (R, 16, d_in))
-            y = jnp.einsum("rbi,io->rbo", x, Wtrue)
-            if (t + 1) % 4 == 0:
-                state, _ = ss(state, (x, y), s2)
-            else:
-                state, _ = ls(state, (x, y), s2)
-        # gather the (possibly zero1-sharded) master
-        w = np.asarray(jax.device_get(state.master["w"]))
-        masters.append(w)
+    state = init_fn(params)
+    ls, ss = jax.jit(local_step), jax.jit(sync_step)
+    key = jax.random.PRNGKey(1)
+    for t in range(16):
+        key, s1, s2 = jax.random.split(key, 3)
+        x = jax.random.normal(s1, (R, 16, d_in))
+        y = jnp.einsum("rbi,io->rbo", x, Wtrue)
+        if (t + 1) % 4 == 0:
+            state, _ = ss(state, (x, y), s2)
+        else:
+            state, _ = ls(state, (x, y), s2)
+    # gather the (possibly zero1-sharded) master
+    w = np.asarray(jax.device_get(state.master["w"]))
+    masters.append(w)
 np.testing.assert_allclose(masters[0], masters[1], rtol=1e-5, atol=1e-6)
 print("ZERO1 EQUIV OK")
 """
@@ -160,28 +158,27 @@ for aggregate, disp in (("dense_psum", "reference"),
     init_fn, local_step, sync_step = make_dist_steps(
         grad_fn, sgd(), ShardCompressor("topk", 0.05, dispatch=disp),
         constant(0.05), mesh, ("data",), specs, aggregate=aggregate)
-    with jax.set_mesh(mesh):
-        state = init_fn(params)
-        key = jax.random.PRNGKey(1)
-        kb, _ = jax.random.split(key)
-        x = jax.random.normal(kb, (R, 8, d_in))
+    state = init_fn(params)
+    key = jax.random.PRNGKey(1)
+    kb, _ = jax.random.split(key)
+    x = jax.random.normal(kb, (R, 8, d_in))
+    y = jnp.einsum("rbi,io->rbo", x, Wtrue)
+    lowered = jax.jit(sync_step).lower(state, (x, y), key).as_text()
+    if aggregate == "sparse_allgather":
+        # the kernel compact path is sort-free end to end
+        assert "top_k" not in lowered, "lax.top_k leaked into sparse sync"
+        assert "sort(" not in lowered, "sort leaked into sparse sync"
+    ls, ss = jax.jit(local_step), jax.jit(sync_step)
+    for t in range(12):
+        key, s1, s2 = jax.random.split(key, 3)
+        x = jax.random.normal(s1, (R, 8, d_in))
         y = jnp.einsum("rbi,io->rbo", x, Wtrue)
-        lowered = jax.jit(sync_step).lower(state, (x, y), key).as_text()
-        if aggregate == "sparse_allgather":
-            # the kernel compact path is sort-free end to end
-            assert "top_k" not in lowered, "lax.top_k leaked into sparse sync"
-            assert "sort(" not in lowered, "sort leaked into sparse sync"
-        ls, ss = jax.jit(local_step), jax.jit(sync_step)
-        for t in range(12):
-            key, s1, s2 = jax.random.split(key, 3)
-            x = jax.random.normal(s1, (R, 8, d_in))
-            y = jnp.einsum("rbi,io->rbo", x, Wtrue)
-            if (t + 1) % 4 == 0:
-                state, loss = ss(state, (x, y), s2)
-            else:
-                state, loss = ls(state, (x, y), s2)
-        masters.append(np.asarray(jax.device_get(state.master["w"])))
-        bits.append(float(state.bits))
+        if (t + 1) % 4 == 0:
+            state, loss = ss(state, (x, y), s2)
+        else:
+            state, loss = ls(state, (x, y), s2)
+    masters.append(np.asarray(jax.device_get(state.master["w"])))
+    bits.append(float(state.bits))
 # identical math, different wire format: same masters, same counted bits
 np.testing.assert_allclose(masters[0], masters[1], rtol=1e-4, atol=1e-5)
 np.testing.assert_allclose(bits[0], bits[1])
@@ -226,18 +223,17 @@ def run(aggregate, disp, downlink, ddisp):
         grad_fn, sgd(), ShardCompressor("topk", 0.05, dispatch=disp),
         constant(0.05), mesh, ("data",), specs, aggregate=aggregate,
         downlink=dl)
-    with jax.set_mesh(mesh):
-        state = init_fn(params)
-        ls, ss = jax.jit(ls_), jax.jit(ss_)
-        key = jax.random.PRNGKey(1)
-        for t in range(12):
-            key, s1, s2 = jax.random.split(key, 3)
-            x = jax.random.normal(s1, (R, 8, d_in))
-            y = jnp.einsum("rbi,io->rbo", x, Wtrue)
-            if (t + 1) % 4 == 0:
-                state, loss = ss(state, (x, y), s2)
-            else:
-                state, loss = ls(state, (x, y), s2)
+    state = init_fn(params)
+    ls, ss = jax.jit(ls_), jax.jit(ss_)
+    key = jax.random.PRNGKey(1)
+    for t in range(12):
+        key, s1, s2 = jax.random.split(key, 3)
+        x = jax.random.normal(s1, (R, 8, d_in))
+        y = jnp.einsum("rbi,io->rbo", x, Wtrue)
+        if (t + 1) % 4 == 0:
+            state, loss = ss(state, (x, y), s2)
+        else:
+            state, loss = ls(state, (x, y), s2)
     return state
 
 # compressed downlink: the dense-psum and sparse-allgather paths must
@@ -283,6 +279,78 @@ def test_downlink_dense_sparse_parity(subproc):
     assert "DOWNLINK PARITY OK" in out
 
 
+TP_KERNEL_PARITY = r"""
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import PartitionSpec as P, NamedSharding
+from repro.launch.mesh import make_mesh
+from repro.core.distributed import make_dist_steps, ShardCompressor
+from repro.optim import sgd, constant
+
+# TP=2: the Top_k kernels run in a nested region that splits their rows
+# over 'model' (kernels/dispatch.py _split_rows); traced, like the
+# launcher, without a jax.set_mesh context
+mesh = make_mesh((4, 2), ("data", "model"))
+R, d_in, d_out = 4, 256, 16
+params = {"w": jnp.zeros((d_in, d_out)), "b": jnp.zeros((d_out,))}
+specs = {"w": P(None, "model"), "b": P("model")}
+params = jax.device_put(params, jax.tree.map(
+    lambda s: NamedSharding(mesh, s), specs,
+    is_leaf=lambda z: isinstance(z, P)))
+Wtrue = 0.3 * jax.random.normal(jax.random.PRNGKey(0), (d_in, d_out))
+
+def grad_fn(p, batch):
+    x, y = batch
+    f = lambda pp: jnp.mean((x @ pp["w"] + pp["b"] - y) ** 2)
+    return jax.value_and_grad(f)(p)
+
+def run(wire, disp, downlink):
+    dl = ShardCompressor("topk", 0.1, dispatch=disp) if downlink else None
+    init_fn, ls_, ss_ = make_dist_steps(
+        grad_fn, sgd(), ShardCompressor("topk", 0.05, dispatch=disp),
+        constant(0.05), mesh, ("data",), specs, wire=wire, downlink=dl)
+    state = init_fn(params)
+    ls, ss = jax.jit(ls_), jax.jit(ss_)
+    lowered = ss.lower(state, (jnp.zeros((R, 8, d_in)),
+                               jnp.zeros((R, 8, d_out))),
+                       jax.random.PRNGKey(0)).as_text()
+    key = jax.random.PRNGKey(1)
+    for t in range(8):
+        key, s1, s2 = jax.random.split(key, 3)
+        x = jax.random.normal(s1, (R, 8, d_in))
+        y = jnp.einsum("rbi,io->rbo", x, Wtrue)
+        if (t + 1) % 4 == 0:
+            state, loss = ss(state, (x, y), s2)
+        else:
+            state, loss = ls(state, (x, y), s2)
+    return state, lowered
+
+for wire in ("dense_psum", "sparse_allgather"):
+    for downlink in (False, True):
+        s_kernel, low = run(wire, "kernel", downlink)
+        s_ref, _ = run(wire, "reference", downlink)
+        # the kernel ran per 'model' shard: a manual region nested in
+        # the engine's own
+        assert low.count("sdy.manual_computation") >= 2, low
+        fields = ["master", "local", "memory"] + (
+            ["view", "down_memory"] if downlink else [])
+        for f in fields:
+            np.testing.assert_array_equal(
+                np.asarray(getattr(s_kernel, f)["w"]),
+                np.asarray(getattr(s_ref, f)["w"]), err_msg=f)
+        assert float(s_kernel.bits) == float(s_ref.bits)
+        assert float(s_kernel.bits_down) == float(s_ref.bits_down)
+        print("TP KERNEL PARITY OK", wire, downlink, float(s_kernel.bits))
+"""
+
+
+def test_tp_kernel_reference_parity(subproc):
+    """On a TP=2 mesh the Top_k kernels (rows split over 'model') match
+    the reference dispatch bit for bit on states and both ledgers,
+    uplink and downlink, over both wires."""
+    out = subproc(TP_KERNEL_PARITY, devices=8)
+    assert out.count("TP KERNEL PARITY OK") == 4
+
+
 HETERO_POLICY = r"""
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P, NamedSharding
@@ -321,18 +389,17 @@ def run(aggregate, disp):
     init_fn, ls_, ss_ = make_dist_steps(
         grad_fn, sgd(), comp, constant(0.05), mesh, ("data",), specs,
         aggregate=aggregate)
-    with jax.set_mesh(mesh):
-        state = init_fn(params)
-        ls, ss = jax.jit(ls_), jax.jit(ss_)
-        key = jax.random.PRNGKey(1)
-        for t in range(12):
-            key, s1, s2 = jax.random.split(key, 3)
-            x = jax.random.normal(s1, (R, 8, d_in))
-            y = jnp.einsum("rbi,io->rbo", x, Wtrue)
-            if (t + 1) % 4 == 0:
-                state, loss = ss(state, (x, y), s2)
-            else:
-                state, loss = ls(state, (x, y), s2)
+    state = init_fn(params)
+    ls, ss = jax.jit(ls_), jax.jit(ss_)
+    key = jax.random.PRNGKey(1)
+    for t in range(12):
+        key, s1, s2 = jax.random.split(key, 3)
+        x = jax.random.normal(s1, (R, 8, d_in))
+        y = jnp.einsum("rbi,io->rbo", x, Wtrue)
+        if (t + 1) % 4 == 0:
+            state, loss = ss(state, (x, y), s2)
+        else:
+            state, loss = ls(state, (x, y), s2)
     return state
 
 # the dense leg runs the reference path, the sparse leg the compact
@@ -385,18 +452,17 @@ def grad_fn(p, batch):
 init_fn, local_step, sync_step = make_dist_steps(
     grad_fn, sgd(), ShardCompressor("topk", 0.5), constant(0.1),
     mesh, ("pod", "data"), specs)
-with jax.set_mesh(mesh):
-    state = init_fn(params)
-    ls, ss = jax.jit(local_step), jax.jit(sync_step)
-    key = jax.random.PRNGKey(1)
-    for t in range(160):
-        key, s1, s2 = jax.random.split(key, 3)
-        x = jax.random.normal(s1, (R, 16, d_in))
-        y = jnp.einsum("rbi,io->rbo", x, Wtrue)
-        if (t + 1) % 4 == 0:
-            state, loss = ss(state, (x, y), s2)
-        else:
-            state, loss = ls(state, (x, y), s2)
+state = init_fn(params)
+ls, ss = jax.jit(local_step), jax.jit(sync_step)
+key = jax.random.PRNGKey(1)
+for t in range(160):
+    key, s1, s2 = jax.random.split(key, 3)
+    x = jax.random.normal(s1, (R, 16, d_in))
+    y = jnp.einsum("rbi,io->rbo", x, Wtrue)
+    if (t + 1) % 4 == 0:
+        state, loss = ss(state, (x, y), s2)
+    else:
+        state, loss = ls(state, (x, y), s2)
 assert float(loss) < 0.1, float(loss)
 print("MULTIPOD OK", float(loss))
 """
@@ -444,29 +510,27 @@ for agg in ("dense_psum", "sparse_allgather"):
         init_fn, ls, ss = make_dist_steps(
             grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
             **common)
-        with jax.set_mesh(mesh):
-            st = init_fn(params)
-            lsj, ssj = jax.jit(ls), jax.jit(ss)
-            key = jax.random.PRNGKey(1)
-            ref_losses = []
-            for t in range(T):
-                key, sub = jax.random.split(key)
-                step = ssj if (t + 1) % H == 0 else lsj
-                st, loss = step(st, bs[t], sub)
-                ref_losses.append(float(loss))
-            ref = st
+        st = init_fn(params)
+        lsj, ssj = jax.jit(ls), jax.jit(ss)
+        key = jax.random.PRNGKey(1)
+        ref_losses = []
+        for t in range(T):
+            key, sub = jax.random.split(key)
+            step = ssj if (t + 1) % H == 0 else lsj
+            st, loss = step(st, bs[t], sub)
+            ref_losses.append(float(loss))
+        ref = st
         init_fn2, round_fn = make_dist_round(
             grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
             **common)
-        with jax.set_mesh(mesh):
-            st2 = init_fn2(params)
-            key = jax.random.PRNGKey(1)
-            losses2 = []
-            for r0 in range(0, T, H):
-                block = jax.tree_util.tree_map(
-                    lambda *xs: jnp.stack(xs), *bs[r0:r0 + H])
-                st2, larr, key = round_fn(st2, block, key)
-                losses2.extend(np.asarray(larr).tolist())
+        st2 = init_fn2(params)
+        key = jax.random.PRNGKey(1)
+        losses2 = []
+        for r0 in range(0, T, H):
+            block = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *bs[r0:r0 + H])
+            st2, larr, key = round_fn(st2, block, key)
+            losses2.extend(np.asarray(larr).tolist())
         # bit-for-bit: states and both direction ledgers
         np.testing.assert_array_equal(np.asarray(ref.master["w"]),
                                       np.asarray(st2.master["w"]))
@@ -491,10 +555,9 @@ init_fn3, round_fn3 = make_dist_round(
     grad_fn, sgd(), ShardCompressor("topk", 0.25), constant(0.1),
     mesh2, ("data",), specs)
 bs2 = [(b[0][:4], b[1][:4]) for b in bs[:4]]
-with jax.set_mesh(mesh2):
-    st3 = init_fn3(params2)
-    block = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *bs2)
-    st3, larr, _ = round_fn3(st3, block, jax.random.PRNGKey(1))
+st3 = init_fn3(params2)
+block = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *bs2)
+st3, larr, _ = round_fn3(st3, block, jax.random.PRNGKey(1))
 assert np.all(np.isfinite(np.asarray(larr)))
 assert int(st3.rounds) == 1
 print("ROUND TP2 OK")

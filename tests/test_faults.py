@@ -538,14 +538,13 @@ def run_fault(wire, spec, sw="uniform"):
     init_fn, fls, fss = make_dist_fault_steps(
         grad_fn, inner, comp, constant(0.1), mesh, ("data",), specs,
         queue_depth=spec.depth, wire=wire, staleness_weight=sw)
-    with jax.set_mesh(mesh):
-        state = init_fn(params)
-        jls, jss = jax.jit(fls), jax.jit(fss)
-        key = jax.random.PRNGKey(1)
-        for t, b in enumerate(batches()):
-            key, sub = jax.random.split(key)
-            row = engine.index_rows(rows, t)
-            state, loss = (jss if events[t] else jls)(state, b, row, sub)
+    state = init_fn(params)
+    jls, jss = jax.jit(fls), jax.jit(fss)
+    key = jax.random.PRNGKey(1)
+    for t, b in enumerate(batches()):
+        key, sub = jax.random.split(key)
+        row = engine.index_rows(rows, t)
+        state, loss = (jss if events[t] else jls)(state, b, row, sub)
     return state
 
 chaos = scn.FaultSpec(max_delay=3, drop=0.15, crash=((1, 4, 9),), seed=5)
@@ -569,16 +568,15 @@ st = run_fault("dense_psum", scn.FaultSpec())
 init_fn, lsn, ssn = make_dist_steps(
     grad_fn, inner, comp, constant(0.1), mesh, ("data",), specs,
     partial=True)
-with jax.set_mesh(mesh):
-    state = init_fn(params)
-    jl, js = jax.jit(lsn), jax.jit(ssn)
-    key = jax.random.PRNGKey(1)
-    for t, b in enumerate(batches()):
-        key, sub = jax.random.split(key)
-        if mask[t].any():
-            state, _ = js(state, b, sub, jnp.asarray(mask[t]))
-        else:
-            state, _ = jl(state, b, sub)
+state = init_fn(params)
+jl, js = jax.jit(lsn), jax.jit(ssn)
+key = jax.random.PRNGKey(1)
+for t, b in enumerate(batches()):
+    key, sub = jax.random.split(key)
+    if mask[t].any():
+        state, _ = js(state, b, sub, jnp.asarray(mask[t]))
+    else:
+        state, _ = jl(state, b, sub)
 np.testing.assert_allclose(np.asarray(st.master["w"]),
                            np.asarray(state.master["w"]),
                            rtol=1e-6, atol=1e-7)
@@ -603,13 +601,12 @@ def run_fault_rounds(wire, spec):
         queue_depth=spec.depth, wire=wire, staleness_weight="uniform")
     plans = rnd.compile_fault_rounds(mask, tables)
     bs = batches()
-    with jax.set_mesh(mesh):
-        state = init_fn(params)
-        key = jax.random.PRNGKey(1)
-        for p in plans:
-            block = stack_block(bs[p.start:p.stop])
-            rblock = engine.index_rows(rows, slice(p.start, p.stop))
-            state, losses, key = round_fn(state, block, rblock, key)
+    state = init_fn(params)
+    key = jax.random.PRNGKey(1)
+    for p in plans:
+        block = stack_block(bs[p.start:p.stop])
+        rblock = engine.index_rows(rows, slice(p.start, p.stop))
+        state, losses, key = round_fn(state, block, rblock, key)
     return state
 
 for wire in ("dense_psum", "sparse_allgather"):

@@ -54,6 +54,32 @@ def test_topk_compact_matches_ref(rows, n, k, kcap, dtype, sign):
                                rtol=1e-5, atol=1e-5)
 
 
+def _dot_precisions(jaxpr, out):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append(eqn.params["precision"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _dot_precisions(sub, out)
+    return out
+
+
+@pytest.mark.parametrize("which", ["kernel", "oracle"])
+def test_compact_slot_scatter_is_exact(which):
+    """The compact form carries survivor indices and f32 values through
+    one-hot matmuls.  On a TPU the default matmul precision rounds the
+    operands to bf16, which corrupts any index above 256 and every f32
+    value, while the CPU multiplies exactly; so every such matmul must
+    ask for HIGHEST precision, in the kernel and in its oracle."""
+    from repro.kernels import topk_compress as tk
+    fn = {"kernel": lambda a: tk.topk_compact(a, 4, 128, interpret=True),
+          "oracle": lambda a: ref.topk_compact_ref(a, 4, 128)}[which]
+    precs = _dot_precisions(
+        jax.make_jaxpr(fn)(jnp.zeros((8, 512))).jaxpr, [])
+    assert len(precs) == 2, precs
+    hi = jax.lax.Precision.HIGHEST
+    assert all(p == (hi, hi) for p in precs), precs
+
+
 @pytest.mark.parametrize("rows,n,k", [(8, 512, 32), (3, 300, 7)])
 def test_topk_compress_selects_topk(rows, n, k):
     """Bisection selection must contain >= k entries per row and every

@@ -45,13 +45,19 @@ def test_train_main_in_process(tmp_path, monkeypatch, restore_cache_dir):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
     res = train.main(["--arch", "yi-6b", "--smoke", "--mesh", "1x1",
                       "--steps", "3", "--H", "2", "--seq", "16",
-                      "--batch", "1", "--ckpt", str(tmp_path / "ck")])
+                      "--batch", "1", "--ckpt", str(tmp_path / "ck")],
+                     return_master=True)
     assert res["steps"] == [1, 2, 3]
     assert np.all(np.isfinite(res["losses"]))
     assert res["rounds"] == 2 and res["workers"] == 1
     assert res["bits_up"][1] > 0          # the step-2 sync charged bits
     assert res["state_devices"] == [jax.devices()[0].id]
     assert os.path.isdir(tmp_path / "ck")
+    # host copies of the master before and after: same tree, and moved
+    m0, m = (jax.tree_util.tree_leaves(res[k]) for k in ("master0", "master"))
+    assert [x.shape for x in m0] == [x.shape for x in m]
+    assert all(isinstance(x, np.ndarray) for x in m0 + m)
+    assert any(not np.array_equal(a, b) for a, b in zip(m0, m))
 
 
 def test_mesh_helper_axes_are_auto():

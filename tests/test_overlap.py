@@ -294,20 +294,19 @@ for dl in (None, ShardCompressor("topk", 0.5)):
     init2, multi_fn = make_dist_multiround(
         grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
         downlink=dl)
-    with jax.set_mesh(mesh):
-        st = init_fn(params)
-        key = jax.random.PRNGKey(1)
-        ref_losses = []
-        for r0 in range(0, T, H):
-            block = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *bs[r0:r0 + H])
-            st, larr, key = round_fn(st, block, key)
-            ref_losses.append(np.asarray(larr))
-        st2 = init2(params)
-        blocks = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs).reshape((T // H, H) + xs[0].shape),
-            *bs)
-        st2, larr2, _ = multi_fn(st2, blocks, jax.random.PRNGKey(1))
+    st = init_fn(params)
+    key = jax.random.PRNGKey(1)
+    ref_losses = []
+    for r0 in range(0, T, H):
+        block = jax.tree_util.tree_map(
+            lambda *xs: jnp.stack(xs), *bs[r0:r0 + H])
+        st, larr, key = round_fn(st, block, key)
+        ref_losses.append(np.asarray(larr))
+    st2 = init2(params)
+    blocks = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs).reshape((T // H, H) + xs[0].shape),
+        *bs)
+    st2, larr2, _ = multi_fn(st2, blocks, jax.random.PRNGKey(1))
     np.testing.assert_array_equal(np.stack(ref_losses), np.asarray(larr2))
     np.testing.assert_array_equal(np.asarray(st.master["w"]),
                                   np.asarray(st2.master["w"]))
@@ -327,19 +326,18 @@ init2, multi_fn = make_dist_multiround(
     partial=True)
 rngm = np.random.default_rng(3)
 masks = jnp.asarray(rngm.random((T // H, R)) < 0.6)
-with jax.set_mesh(mesh):
-    st = init_fn(params)
-    key = jax.random.PRNGKey(1)
-    ref_losses = []
-    for w, r0 in enumerate(range(0, T, H)):
-        block = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *bs[r0:r0 + H])
-        st, larr, key = round_fn(st, block, masks[w], key)
-        ref_losses.append(np.asarray(larr))
-    st2 = init2(params)
-    blocks = jax.tree_util.tree_map(
-        lambda *xs: jnp.stack(xs).reshape((T // H, H) + xs[0].shape), *bs)
-    st2, larr2, _ = multi_fn(st2, blocks, masks, jax.random.PRNGKey(1))
+st = init_fn(params)
+key = jax.random.PRNGKey(1)
+ref_losses = []
+for w, r0 in enumerate(range(0, T, H)):
+    block = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs), *bs[r0:r0 + H])
+    st, larr, key = round_fn(st, block, masks[w], key)
+    ref_losses.append(np.asarray(larr))
+st2 = init2(params)
+blocks = jax.tree_util.tree_map(
+    lambda *xs: jnp.stack(xs).reshape((T // H, H) + xs[0].shape), *bs)
+st2, larr2, _ = multi_fn(st2, blocks, masks, jax.random.PRNGKey(1))
 np.testing.assert_array_equal(np.stack(ref_losses), np.asarray(larr2))
 np.testing.assert_array_equal(np.asarray(st.master["w"]),
                               np.asarray(st2.master["w"]))

@@ -454,16 +454,15 @@ def run(wire):
     init_fn, ls_, ss_ = make_dist_steps(
         grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
         wire=wire, aggregate="{aggregate}", partial=True)
-    with jax.set_mesh(mesh):
-        st = init_fn(params)
-        ls, ss = jax.jit(ls_), jax.jit(ss_)
-        k = jax.random.PRNGKey(1)
-        for t in range(T):
-            k, sub = jax.random.split(k)
-            if (t + 1) % H == 0:
-                st, _ = ss(st, bs[t], sub, mask[t])
-            else:
-                st, _ = ls(st, bs[t], sub)
+    st = init_fn(params)
+    ls, ss = jax.jit(ls_), jax.jit(ss_)
+    k = jax.random.PRNGKey(1)
+    for t in range(T):
+        k, sub = jax.random.split(k)
+        if (t + 1) % H == 0:
+            st, _ = ss(st, bs[t], sub, mask[t])
+        else:
+            st, _ = ls(st, bs[t], sub)
     return jax.device_get(st)
 
 sd, sp = run("dense_psum"), run("sparse_allgather")
@@ -478,13 +477,12 @@ comp = ShardCompressor("topk", 0.25)
 init_fn, round_fn = make_dist_round(
     grad_fn, sgd(), comp, constant(0.1), mesh, ("data",), specs,
     wire="dense_psum", aggregate="{aggregate}", partial=True)
-with jax.set_mesh(mesh):
-    st2 = init_fn(params)
-    k = jax.random.PRNGKey(1)
-    for r0 in range(0, T, H):
-        blk = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *bs[r0:r0 + H])
-        st2, _, k = round_fn(st2, blk, mask[r0 + H - 1], k)
+st2 = init_fn(params)
+k = jax.random.PRNGKey(1)
+for r0 in range(0, T, H):
+    blk = jax.tree_util.tree_map(
+        lambda *xs: jnp.stack(xs), *bs[r0:r0 + H])
+    st2, _, k = round_fn(st2, blk, mask[r0 + H - 1], k)
 st2 = jax.device_get(st2)
 np.testing.assert_array_equal(np.asarray(sd.master["w"]),
                               np.asarray(st2.master["w"]))
