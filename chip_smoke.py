@@ -75,8 +75,7 @@ def phase_train(train):
     res = train.main(train_args(LAYERS, "1x1", 8, 4, "--dispatch", "auto",
                                 "--ckpt", str(ck)))
     log(f"[train] layers={res['layers']} steps={len(res['losses'])} "
-        f"seconds={time.time() - t:.1f} first_result_s="
-        f"{res['first_result_seconds']:.1f} launches={res['launches']}")
+        f"seconds={time.time() - t:.1f} launches={res['launches']}")
     log(f"[train] losses={res['losses']}")
     log(f"[train] bits_up={res['bits_up']}")
     if not np.all(np.isfinite(res["losses"])):
@@ -203,8 +202,8 @@ def four_chips(train):
         t = time.time()
         res = runs[wire] = train.main(args + ["--wire", wire],
                                       return_master=True)
-        log(f"[4x1 {wire}] seconds={time.time() - t:.1f} first_result_s="
-            f"{res['first_result_seconds']:.1f} launches={res['launches']}")
+        log(f"[4x1 {wire}] seconds={time.time() - t:.1f} "
+            f"launches={res['launches']}")
         log(f"[4x1 {wire}] losses={res['losses']}")
         log(f"[4x1 {wire}] bits_up={res['bits_up']}")
         log(f"[4x1 {wire}] state_devices={res['state_devices']} "

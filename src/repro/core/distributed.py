@@ -86,6 +86,23 @@ class DistQsparseState(NamedTuple):
     inflight_tau: Any = None  # int32 [R, depth]
 
 
+#: the ``jax.named_scope`` of each stage of the round program built by
+#: ``make_dist_steps``.  Every device operation of a stage carries its
+#: scope in the HLO ``op_name`` (``.../qsparse.grad/transpose(jvp())/
+#: dot_general``) and so in a profiler trace's ``tf_op``; scopes never
+#: nest, so an operation falls under at most one.  Metadata only: the
+#: compiled arithmetic is the same with or without them.
+ROUND_SCOPES = (
+    "qsparse.grad",           # model forward and backward
+    "qsparse.optimizer",      # learning rate, momentum, half step
+    "qsparse.compress",       # uplink accumulator, Top_k, masking
+    "qsparse.exchange",       # cross-worker collectives
+    "qsparse.decode",         # sparse wire: compact buffers -> dense mean
+    "qsparse.master_update",  # new master and its broadcast
+    "qsparse.downlink",       # compressed server -> worker channel
+)
+
+
 # ---------------------------------------------------------------------------
 # shard-local compression
 # ---------------------------------------------------------------------------
@@ -634,20 +651,31 @@ def make_dist_steps(
         return jax.tree_util.tree_map(lambda x: x[None], tree)
 
     # ---- local phase (shared) ------------------------------------------
-    def _local(master, local, memory, inner, step, batch, lr):
-        params = _squeeze(local)
-        data = _squeeze(batch)
-        loss, grads = grad_fn(params, data)
-        updates, inner_new = inner_opt.update(grads, _squeeze(inner), params, lr)
-        half = apply_updates(params, updates)
+    # Each stage's outputs leave the manual region through ``_expand``
+    # inside that stage's scope: XLA names a fusion after its root, and
+    # the root is often that reshape.
+    def _local(master, local, memory, inner, step, batch):
+        """(half step, the inner state with its worker axis, loss)."""
+        with jax.named_scope("qsparse.grad"):
+            params = _squeeze(local)
+            loss, grads = grad_fn(params, _squeeze(batch))
+        with jax.named_scope("qsparse.optimizer"):
+            lr = lr_schedule(step)
+            updates, inner_new = inner_opt.update(grads, _squeeze(inner),
+                                                  params, lr)
+            half = apply_updates(params, updates)
+            inner_new = _expand(inner_new)
         return half, inner_new, loss
 
     # ---- local step -----------------------------------------------------
     def local_body(master, local, memory, inner, step, batch, key):
-        lr = lr_schedule(step)
-        half, inner_new, loss = _local(master, local, memory, inner, step, batch, lr)
-        loss = jax.lax.pmean(loss, daxes)
-        return _expand(half), _expand(inner_new), loss
+        half, inner_new, loss = _local(master, local, memory, inner, step,
+                                       batch)
+        with jax.named_scope("qsparse.optimizer"):
+            half = _expand(half)
+        with jax.named_scope("qsparse.exchange"):
+            loss = jax.lax.pmean(loss, daxes)
+        return half, inner_new, loss
 
     # ---- aggregation rules (DESIGN.md §8) -------------------------------
     def _aggregate_psum(g, s_f):
@@ -693,38 +721,37 @@ def make_dist_steps(
         down_mem = rest.pop(0) if with_down else None
         smask = rest.pop(0) if partial else None
         step, batch, key = rest
-        lr = lr_schedule(step)
         half, inner_new, loss = _local(master, local, memory, inner, step,
-                                       batch, lr)
-        mem = _squeeze(memory)
+                                       batch)
         # zero1 masters are sharded on axis 0 over the worker axes:
         # materialize the full master for the delta via all_gather
-        full_master = _gather_master(master, z1)
-        ref = _squeeze(view) if carry_view else full_master
-        delta = jax.tree_util.tree_map(
-            lambda m, x, h: m + x.astype(jnp.float32) - h.astype(jnp.float32),
-            mem, ref, half,
-        )
-        g, new_mem, wire_bits = up.apply(
-            delta, param_specs, key=jax.random.fold_in(key, 1))
-        if partial:
-            s = smask[0]
-            s_f = s.astype(jnp.float32)
-            g = jax.tree_util.tree_map(
-                lambda gg: jnp.where(s, gg, jnp.zeros_like(gg)), g)
-            new_mem = jax.tree_util.tree_map(
-                lambda old, nm: jnp.where(s, nm, old), mem, new_mem)
-            wire_bits = jnp.where(s, wire_bits, 0.0)
-        else:
-            s, s_f = None, jnp.float32(1.0)
-        g_mean = _aggregate_psum(g, s_f)
-        new_full_master = jax.tree_util.tree_map(
-            lambda x, gg: (x.astype(jnp.float32) - gg).astype(x.dtype),
-            full_master, g_mean,
-        )
-        new_master = _scatter_master(new_full_master, z1)
-        total_bits = jax.lax.psum(wire_bits, daxes)
-        loss = jax.lax.pmean(loss, daxes)
+        with jax.named_scope("qsparse.exchange"):
+            full_master = _gather_master(master, z1)
+        with jax.named_scope("qsparse.compress"):
+            mem = _squeeze(memory)
+            ref = _squeeze(view) if carry_view else full_master
+            delta = jax.tree_util.tree_map(
+                lambda m, x, h: m + x.astype(jnp.float32)
+                - h.astype(jnp.float32),
+                mem, ref, half,
+            )
+            g, new_mem, wire_bits = up.apply(
+                delta, param_specs, key=jax.random.fold_in(key, 1))
+            if partial:
+                s = smask[0]
+                s_f = s.astype(jnp.float32)
+                g = jax.tree_util.tree_map(
+                    lambda gg: jnp.where(s, gg, jnp.zeros_like(gg)), g)
+                new_mem = jax.tree_util.tree_map(
+                    lambda old, nm: jnp.where(s, nm, old), mem, new_mem)
+                wire_bits = jnp.where(s, wire_bits, 0.0)
+            else:
+                s, s_f = None, jnp.float32(1.0)
+            mem_out = _expand(new_mem)
+        with jax.named_scope("qsparse.exchange"):
+            g_mean = _aggregate_psum(g, s_f)
+            total_bits = jax.lax.psum(wire_bits, daxes)
+            loss = jax.lax.pmean(loss, daxes)
 
         def picked(new, old):
             """Per-worker select: the new value only where s_r."""
@@ -733,48 +760,55 @@ def make_dist_steps(
             return jax.tree_util.tree_map(
                 lambda n, o: jnp.where(s, n.astype(o.dtype), o), new, old)
 
-        if not with_down:
-            new_local = picked(new_full_master, half)
-            out = (
-                new_master,
-                _expand(new_local),   # exact broadcast (syncers only)
-                _expand(new_mem),
-                _expand(inner_new),
+        with jax.named_scope("qsparse.master_update"):
+            new_full_master = jax.tree_util.tree_map(
+                lambda x, gg: (x.astype(jnp.float32) - gg).astype(x.dtype),
+                full_master, g_mean,
             )
-            if carry_view:
-                out = out + (_expand(picked(new_full_master, ref)),)
-            return out + (total_bits, loss)
+            new_master = _scatter_master(new_full_master, z1)
+            if not with_down:
+                new_local = picked(new_full_master, half)
+                out = (
+                    new_master,
+                    _expand(new_local),   # exact broadcast (syncers only)
+                    mem_out,
+                    inner_new,
+                )
+                if carry_view:
+                    out = out + (_expand(picked(new_full_master, ref)),)
+                return out + (total_bits, loss)
         # downlink: error-compensated compression of the master delta
-        dm = _squeeze(down_mem)
-        dacc = jax.tree_util.tree_map(
-            lambda d, nm, vv: d + nm.astype(jnp.float32)
-            - vv.astype(jnp.float32),
-            dm, new_full_master, ref,
-        )
-        q, new_dm, dbits = down.apply(
-            dacc, param_specs, key=jax.random.fold_in(key, 2))
-        new_view = jax.tree_util.tree_map(
-            lambda vv, qq: (vv.astype(jnp.float32) + qq).astype(vv.dtype),
-            ref, q,
-        )
-        if partial:
-            new_view = picked(new_view, ref)
-            new_dm = jax.tree_util.tree_map(
-                lambda old, nm: jnp.where(s, nm, old), dm, new_dm)
-            dbits = jnp.where(s, dbits, 0.0)
-        new_local = picked(new_view, half)
-        total_down = jax.lax.psum(dbits, daxes)
-        return (
-            new_master,
-            _expand(new_local),  # x̂_{t+1} = x_{t+1} = view (syncers)
-            _expand(new_mem),
-            _expand(inner_new),
-            _expand(new_view),
-            _expand(new_dm),
-            total_bits,
-            total_down,
-            loss,
-        )
+        with jax.named_scope("qsparse.downlink"):
+            dm = _squeeze(down_mem)
+            dacc = jax.tree_util.tree_map(
+                lambda d, nm, vv: d + nm.astype(jnp.float32)
+                - vv.astype(jnp.float32),
+                dm, new_full_master, ref,
+            )
+            q, new_dm, dbits = down.apply(
+                dacc, param_specs, key=jax.random.fold_in(key, 2))
+            new_view = jax.tree_util.tree_map(
+                lambda vv, qq: (vv.astype(jnp.float32) + qq).astype(vv.dtype),
+                ref, q,
+            )
+            if partial:
+                new_view = picked(new_view, ref)
+                new_dm = jax.tree_util.tree_map(
+                    lambda old, nm: jnp.where(s, nm, old), dm, new_dm)
+                dbits = jnp.where(s, dbits, 0.0)
+            new_local = picked(new_view, half)
+            total_down = jax.lax.psum(dbits, daxes)
+            return (
+                new_master,
+                _expand(new_local),  # x̂_{t+1} = x_{t+1} = view (syncers)
+                mem_out,
+                inner_new,
+                _expand(new_view),
+                _expand(new_dm),
+                total_bits,
+                total_down,
+                loss,
+            )
       return sync_body
 
     # ---- spec plumbing ---------------------------------------------------
@@ -933,45 +967,54 @@ def make_dist_steps(
         view = rest.pop(0) if carry_view else None
         smask = rest.pop(0) if partial else None
         step, batch, key = rest
-        lr = lr_schedule(step)
         half, inner_new, loss = _local(master, local, memory, inner, step,
-                                       batch, lr)
-        mem = _squeeze(memory)
+                                       batch)
         # with a compressed downlink (or a partial-participation run)
         # the uplink reference point is the worker's lagging view, not
         # the true master
-        ref = _squeeze(view) if carry_view else _gather_master(master, z1)
-        delta = jax.tree_util.tree_map(
-            lambda m, x, h: m + x.astype(jnp.float32) - h.astype(jnp.float32),
-            mem, ref, half,
-        )
-        payloads, _treedef, wire_bits, new_mem = compressor.compact(
-            delta, param_specs, key=jax.random.fold_in(key, 1))
-        if partial:
-            # masked-out workers transmit nothing: zero their payload
-            # values (sentinel-style — the auto-region scatter-add and
-            # the support counts both see zeros), keep their memory
-            s = smask[0]
-            new_mem = jax.tree_util.tree_map(
-                lambda old, nm: jnp.where(s, nm, old), mem, new_mem)
-            wire_bits = jnp.where(s, wire_bits, 0.0)
-            arrays = []
-            for pl in payloads:
-                if pl[0] == "dense":
-                    arrays.append(
-                        jnp.where(s, pl[1], jnp.zeros_like(pl[1])))
-                else:
-                    _, idx, sel, _ax, _moved = pl
-                    arrays.append(idx)
-                    arrays.append(jnp.where(s, sel, jnp.zeros_like(sel)))
+        if carry_view:
+            ref = _squeeze(view)
         else:
-            arrays = _compact_arrays(payloads)
-        total_bits = jax.lax.psum(wire_bits, daxes)
-        loss = jax.lax.pmean(loss, daxes)
-        out = (_expand(new_mem), _expand(inner_new))
+            with jax.named_scope("qsparse.exchange"):
+                ref = _gather_master(master, z1)
+        with jax.named_scope("qsparse.compress"):
+            mem = _squeeze(memory)
+            delta = jax.tree_util.tree_map(
+                lambda m, x, h: m + x.astype(jnp.float32)
+                - h.astype(jnp.float32),
+                mem, ref, half,
+            )
+            payloads, _treedef, wire_bits, new_mem = compressor.compact(
+                delta, param_specs, key=jax.random.fold_in(key, 1))
+            if partial:
+                # masked-out workers transmit nothing: zero their payload
+                # values (sentinel-style — the auto-region scatter-add and
+                # the support counts both see zeros), keep their memory
+                s = smask[0]
+                new_mem = jax.tree_util.tree_map(
+                    lambda old, nm: jnp.where(s, nm, old), mem, new_mem)
+                wire_bits = jnp.where(s, wire_bits, 0.0)
+                arrays = []
+                for pl in payloads:
+                    if pl[0] == "dense":
+                        arrays.append(
+                            jnp.where(s, pl[1], jnp.zeros_like(pl[1])))
+                    else:
+                        _, idx, sel, _ax, _moved = pl
+                        arrays.append(idx)
+                        arrays.append(
+                            jnp.where(s, sel, jnp.zeros_like(sel)))
+            else:
+                arrays = _compact_arrays(payloads)
+            out = (_expand(new_mem), inner_new)
+            wire = [a[None] for a in arrays]
         if partial:
-            out = out + (_expand(half),)
-        return out + ([a[None] for a in arrays], total_bits, loss)
+            with jax.named_scope("qsparse.optimizer"):
+                out = out + (_expand(half),)
+        with jax.named_scope("qsparse.exchange"):
+            total_bits = jax.lax.psum(wire_bits, daxes)
+            loss = jax.lax.pmean(loss, daxes)
+        return out + (wire, total_bits, loss)
       return sparse_sync_body
 
     def make_sparse_down_body():
@@ -1006,36 +1049,9 @@ def make_dist_steps(
         return (_expand(new_dm), [a[None] for a in arrays], total_down)
       return down_body
 
-    def sync_step_sparse(state: DistQsparseState, batch, key,
-                         sync_mask=None):
-        m = _prep_mask(sync_mask) if partial else None
-        z1 = _z1mask(state.master)
-        meta = _leaf_meta(state.master)
-        n_arrays = sum(1 if mt[0] == "dense" else 2 for mt in meta)
-        extra_in, extra_specs = (), ()
-        if carry_view:
-            extra_in += (state.view,)
-            extra_specs += (worker_specs,)
-        if partial:
-            extra_in += (m,)
-            extra_specs += (worker_specs,)
-        half_specs = (worker_specs,) if partial else ()
-        mapped = jax.shard_map(
-            make_sparse_sync_body(z1), mesh=mesh,
-            in_specs=(_master_in_specs(z1), worker_specs, worker_specs,
-                      worker_specs) + extra_specs + (P(), batch_spec, P()),
-            out_specs=(worker_specs, worker_specs) + half_specs
-            + ([P(tuple(daxes))] * n_arrays, P(), P()),
-            axis_names=manual, check_vma=check_vma,
-        )
-        out = mapped(
-            state.master, state.local, state.memory, state.inner,
-            *extra_in, state.step, batch, key)
-        if partial:
-            memory, inner_new, half_all, arrays, wire_bits, loss = out
-        else:
-            memory, inner_new, arrays, wire_bits, loss = out
-            half_all = None
+    def _decode_mean(arrays, meta, master, z1, m):
+        """The workers' mean payload, per leaf, from the gathered
+        sparse-wire buffers."""
         n_sync = (jnp.maximum(jnp.sum(m.astype(jnp.float32)), 1.0)
                   if partial else None)
         # auto-region combine: dense mean per leaf, constrained to the
@@ -1043,7 +1059,7 @@ def make_dist_steps(
         # (zero1 leaves: sharded over the worker axes; each chip
         # reconstructs only its master shard from the gathered compacts).
         it = iter(arrays)
-        master_leaves, mtd = jax.tree_util.tree_flatten(state.master)
+        master_leaves, mtd = jax.tree_util.tree_flatten(master)
         z1_leaves = jax.tree_util.tree_leaves(z1)
         means = []
         for (kind, ax, moved), mleaf, z1m in zip(meta, master_leaves,
@@ -1088,12 +1104,40 @@ def make_dist_steps(
                 if z1spec is not None:
                     cnt = jax.lax.with_sharding_constraint(cnt, z1spec)
                 means.append(dense / jnp.maximum(cnt, 1.0))
-        # zero1 masters keep their global shape (only the sharding
-        # differs), so the update is uniform across both layouts.
-        g_mean = jax.tree_util.tree_unflatten(mtd, means)
-        new_master = jax.tree_util.tree_map(
-            lambda x, gg: (x.astype(jnp.float32) - gg).astype(x.dtype),
-            state.master, g_mean)
+        return jax.tree_util.tree_unflatten(mtd, means)
+
+    def sync_step_sparse(state: DistQsparseState, batch, key,
+                         sync_mask=None):
+        m = _prep_mask(sync_mask) if partial else None
+        z1 = _z1mask(state.master)
+        meta = _leaf_meta(state.master)
+        n_arrays = sum(1 if mt[0] == "dense" else 2 for mt in meta)
+        extra_in, extra_specs = (), ()
+        if carry_view:
+            extra_in += (state.view,)
+            extra_specs += (worker_specs,)
+        if partial:
+            extra_in += (m,)
+            extra_specs += (worker_specs,)
+        half_specs = (worker_specs,) if partial else ()
+        mapped = jax.shard_map(
+            make_sparse_sync_body(z1), mesh=mesh,
+            in_specs=(_master_in_specs(z1), worker_specs, worker_specs,
+                      worker_specs) + extra_specs + (P(), batch_spec, P()),
+            out_specs=(worker_specs, worker_specs) + half_specs
+            + ([P(tuple(daxes))] * n_arrays, P(), P()),
+            axis_names=manual, check_vma=check_vma,
+        )
+        out = mapped(
+            state.master, state.local, state.memory, state.inner,
+            *extra_in, state.step, batch, key)
+        if partial:
+            memory, inner_new, half_all, arrays, wire_bits, loss = out
+        else:
+            memory, inner_new, arrays, wire_bits, loss = out
+            half_all = None
+        with jax.named_scope("qsparse.decode"):
+            g_mean = _decode_mean(arrays, meta, state.master, z1, m)
         rounds_inc = jnp.any(m).astype(jnp.int32) if partial else 1
 
         def _select(old_all):
@@ -1109,9 +1153,19 @@ def make_dist_steps(
                     b, NamedSharding(mesh, P(tuple(daxes))))
             return jax.tree_util.tree_map(leaf, new_master, old_all)
 
+        # zero1 masters keep their global shape (only the sharding
+        # differs), so the update is uniform across both layouts.
+        with jax.named_scope("qsparse.master_update"):
+            new_master = jax.tree_util.tree_map(
+                lambda x, gg: (x.astype(jnp.float32) - gg).astype(x.dtype),
+                state.master, g_mean)
+            if not down_active:
+                new_local = _select(half_all if partial else state.local)
+                new_view = _select(state.view) if carry_view else state.view
         if down_active:
-            new_local, view, down_mem, down_bits = _sparse_downlink(
-                state, new_master, key, m, half_all)
+            with jax.named_scope("qsparse.downlink"):
+                new_local, view, down_mem, down_bits = _sparse_downlink(
+                    state, new_master, key, m, half_all)
             return (
                 DistQsparseState(
                     master=new_master, local=new_local, memory=memory,
@@ -1123,8 +1177,6 @@ def make_dist_steps(
                 ),
                 loss,
             )
-        new_local = _select(half_all if partial else state.local)
-        new_view = _select(state.view) if carry_view else state.view
         down_cost = (jnp.sum(m.astype(jnp.float32))
                      * jnp.float32(down.dense_bits(state.master))
                      if partial else _exact_down_bits(state.master))

@@ -113,6 +113,7 @@ def flash_attention_fwd(q, k, v, *, window: int = -1, q_block: int = 128,
             pltpu.VMEM((qb,), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_prefill",
     )(qt, kt, vt)
     out = out[:, :, :S]
     return jnp.moveaxis(out, 1, 2)
@@ -171,5 +172,6 @@ def flash_decode_fwd(q, k, v, valid, *, interpret: bool = False):
         out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(q4, kt, vt, mask)
     return out.reshape(B, 1, H, hd)

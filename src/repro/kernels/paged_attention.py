@@ -182,5 +182,6 @@ def paged_decode_fwd(q, kp, vp, kscale, vscale, tables, lengths, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(tbl, jnp.asarray(lengths, jnp.int32), *inputs)
     return out.reshape(B, 1, H, hd)

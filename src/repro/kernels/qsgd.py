@@ -56,5 +56,6 @@ def qsgd_quantize(x: jax.Array, u: jax.Array, s: int, *,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="qsgd",
     )(x, u)
     return out[:rows]

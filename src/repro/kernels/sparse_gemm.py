@@ -127,6 +127,7 @@ def sparse_gemm(x: jnp.ndarray, idx: jnp.ndarray, val: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((idxp.shape[0], xp.shape[0]),
                                        jnp.float32),
         interpret=interpret,
+        name="sparse_gemm",
     )(xp, idxp, valp)
     return out[:R, :M].T
 
@@ -179,5 +180,6 @@ def qdq_gemm(x: jnp.ndarray, levels: jnp.ndarray, scale: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((lvp.shape[0], xp.shape[0]),
                                        jnp.float32),
         interpret=interpret,
+        name="qdq_gemm",
     )(xp, lvp, scp)
     return out[:R, :M].T

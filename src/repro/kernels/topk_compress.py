@@ -132,6 +132,7 @@ def topk_compress(acc: jax.Array, k: int, *, iters: int = 24,
         ],
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="topk_compress",
     )(acc)
     cnt = cnt[:, 0]
     if pad:
@@ -265,6 +266,7 @@ def topk_compact(acc: jax.Array, k: int, kcap: int, *, iters: int = 24,
         scratch_shapes=scratch,
         compiler_params=COMPILER_PARAMS,
         interpret=interpret,
+        name="topk_compact",
     )(acc)
     cnt = cnt[:, 0]
     if pad:

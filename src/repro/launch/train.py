@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
-import time
 
 import jax
 import jax.numpy as jnp
@@ -348,12 +347,8 @@ def main(argv=None, *, return_master: bool = False) -> dict:
         return " ".join(f"{k}={v}" for k, v in LAUNCHES.items() if v) or "none"
 
     history = []  # (step, loss, uplink bits) as logged
-    first_s = None  # wall time to the first result (compiles included)
 
     def log_step(t, kind, loss, up, down, note=""):
-        nonlocal first_s
-        if first_s is None:
-            first_s = time.time() - t0
         history.append((t + 1, loss, up))
         print(f"step {t + 1:4d} [{kind}] loss {loss:.4f} "
               f"bits up {up:.3g} down {down:.3g}{note}", flush=True)
@@ -368,7 +363,6 @@ def main(argv=None, *, return_master: bool = False) -> dict:
     # committed like the key the round program hands back, so the
     # second round does not compile the program again
     key = jax.device_put(jax.random.PRNGKey(1), NamedSharding(mesh, P()))
-    t0 = time.time()
     # kernel launches are counted at trace time (launch_stats.py):
     # snapshot after the first sync step traces — with megabuffer
     # packing this shows one launch per operator family per
@@ -522,9 +516,8 @@ def main(argv=None, *, return_master: bool = False) -> dict:
             last_loss = float(loss)
             log_step(t, kind, last_loss, float(state.bits),
                      float(state.bits_down), note)
-    dt = time.time() - t0
     total = float(state.bits) + float(state.bits_down)
-    print(f"\n{args.steps} steps in {dt:.1f}s ({args.steps / dt:.2f} it/s); "
+    print(f"\n{args.steps} steps; "
           f"R={R} workers, {int(state.rounds)} sync rounds, "
           f"{float(state.bits):.3g} uplink + {float(state.bits_down):.3g} "
           f"downlink = {total:.3g} wire bits")
@@ -543,7 +536,6 @@ def main(argv=None, *, return_master: bool = False) -> dict:
         "losses": [h[1] for h in history],
         "bits_up": [h[2] for h in history],
         "launches": dict(LAUNCHES),
-        "first_result_seconds": first_s,
         "rounds": int(state.rounds),
         "workers": R,
         "layers": cfg.n_layers,
