@@ -18,6 +18,7 @@ from benchmarks import roofline
 from repro.core import operators as ops
 from repro.kernels import autotune
 from repro.kernels import dispatch as dsp
+from repro.kernels import topk_compress as _topk
 
 D = 1_000_000   # ~ one large layer
 D_GLOBAL = 1 << 18  # single-kernel-row budget for the global operators
@@ -43,12 +44,16 @@ def _model_bytes(keys):
 
 def _tuned_geometry(keys):
     """derived-string fragment naming the table-resolved block geometry
-    of the entry's (first) launch, or the heuristic default."""
+    of the entry's (first) launch, or the untuned one: Top_k's shape
+    rule, the other kernels' default."""
     if not keys:
         return f"block_rows={dsp.DEFAULT_BLOCK_ROWS}"
     ent = autotune.lookup(*keys[0][:5])
     if ent is None:
-        return f"block_rows={dsp.DEFAULT_BLOCK_ROWS}"
+        k0 = keys[0]
+        br = (_topk.dense_block_rows(k0.rows, k0.row_len)
+              if k0.kernel == "topk_compress" else dsp.DEFAULT_BLOCK_ROWS)
+        return f"block_rows={br}"
     frag = f"block_rows={ent.block_rows}"
     if ent.chunk:
         frag += f";chunk={ent.chunk}"

@@ -18,10 +18,10 @@ Resolution order (DESIGN.md §10):
      trace-time launch signature ``(kernel, dtype, rows, row_len, k,
      sign)`` — hit/miss counters surface in
      ``launch_stats.TUNE_CACHE``;
-  3. untuned shapes fall back to the historical heuristic
-     (``dispatch.DEFAULT_BLOCK_ROWS`` = 8, chunk 128) — so behaviour
-     without a table, off-TPU and in interpret mode, is exactly the
-     pre-autotune dispatch.
+  3. untuned shapes fall back to the kernel's own geometry:
+     ``topk_compress`` sizes its blocks from the rows it tiles
+     (``topk_compress.dense_block_rows``), the other kernels take
+     ``dispatch.DEFAULT_BLOCK_ROWS`` = 8 and chunk 128.
 
 The table persists to ``artifacts/tuning/<device_kind>.json`` (one file
 per accelerator kind; load/merge/save, so repeated tune runs extend the
@@ -62,10 +62,6 @@ from repro.kernels.launch_stats import TUNE_CACHE
 TABLE_VERSION = 1
 DEFAULT_TABLE_DIR = os.path.join("artifacts", "tuning")
 
-#: dense kernels hold 3 f32 blocks of (block_rows, row_len) in VMEM;
-#: candidates stay inside the envelope of the dispatch defaults
-#: (block_rows 8 at max_row 2^17, which compiles for v5e)
-VMEM_DENSE_BYTES = 3 * 8 * (1 << 17) * 4
 #: the compact kernel's (block_rows, chunk, kcap) one-hot scatter
 #: intermediate, at the historical default geometry (8, 128, max_cap)
 VMEM_COMPACT_BYTES = 8 * 128 * (1 << 11) * 4
@@ -275,15 +271,17 @@ def _time_us(fn, *args, iters: int = 3) -> float:
 
 
 def block_row_candidates(rows: int, row_len: int) -> list:
-    """Powers of two up to the row count (clamped), inside the dense
-    VMEM envelope."""
+    """Powers of two up to the row count (clamped) whose (c, row_len)
+    f32 plane fits ``topk_compress.DENSE_PLANE_BYTES``, the envelope of
+    the Top_k kernel's own rule (block_rows 8 at max_row 2^17)."""
     cands = set()
     p = 1
     while p < max(rows, 1):
         cands.add(p)
         p *= 2
     cands.add(rows)
-    out = sorted(c for c in cands if 3 * c * row_len * 4 <= VMEM_DENSE_BYTES)
+    out = sorted(c for c in cands
+                 if c * row_len * 4 <= _topk.DENSE_PLANE_BYTES)
     return out or [min(rows, 8)]
 
 

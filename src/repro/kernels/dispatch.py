@@ -79,13 +79,15 @@ from repro.kernels import qsgd as _qsgd
 from repro.kernels import sparse_gemm as _sgemm
 from repro.kernels import topk_compress as _topk
 from repro.kernels.launch_stats import (  # noqa: F401 — re-exported
-    LAUNCHES, reset_launches, total_launches,
+    BLOCK_ROWS, LAUNCHES, reset_launches, total_launches,
 )
 
 LANES = 128  # TPU vector lane width: kernel rows are padded to this
 
-#: the historical fixed grid geometry — the fallback when a shape has
-#: no tuning-table entry and ``block_rows`` is on auto (None)
+#: the grid height of a ``qsgd``, ``topk_compact`` or serving-GEMM
+#: launch whose shape has no tuning-table entry while ``block_rows`` is
+#: on auto (None); ``topk_compress`` sizes its own
+#: (``topk_compress.dense_block_rows``)
 DEFAULT_BLOCK_ROWS = 8
 DEFAULT_CHUNK = 128
 
@@ -108,11 +110,13 @@ class DispatchConfig:
               kcap) one-hot intermediate of the slot scatter
     block_rows: grid block height handed to the kernels.  ``None``
           (default) resolves per launch signature through the autotune
-          table (kernels/autotune.py; LRU → persisted per-device table
-          → ``DEFAULT_BLOCK_ROWS``), so untuned shapes behave exactly
-          like the historical fixed geometry; an explicit int always
-          wins over the table.  The kernels are row-independent, so the
-          choice changes timing only — outputs are bit-identical.
+          table (kernels/autotune.py; LRU → persisted per-device
+          table); an untuned shape then runs ``topk_compress`` at the
+          height its own rows allow (``topk_compress.dense_block_rows``)
+          and the other kernels at ``DEFAULT_BLOCK_ROWS``.  An explicit
+          int always wins over the table.  The kernels are
+          row-independent, so the choice changes timing only — outputs
+          are bit-identical.
     pack: megabuffer-pack same-bucket leaves in compress_tree (one
           kernel launch per operator family per sync round)
     interpret: None — auto (interpret off-TPU); bool to force
@@ -155,15 +159,24 @@ def _resolve(cfg: Optional[DispatchConfig]) -> DispatchConfig:
 
 
 def _block_rows(cfg: DispatchConfig, kernel: str, rows: int, row_len: int,
-                k: int, sign: bool) -> int:
+                k: int, sign: bool) -> Optional[int]:
     """Resolve one launch's grid height: an explicit
     ``cfg.block_rows`` wins, then the autotune table (hit/miss counters
-    in ``launch_stats.TUNE_CACHE``), then the historical heuristic."""
+    in ``launch_stats.TUNE_CACHE``).  Where neither applies,
+    ``topk_compress`` gets ``None`` (the kernel sizes its blocks from
+    the rows it tiles) and other kernels ``DEFAULT_BLOCK_ROWS``.  A
+    ``topk_compress`` height set here is counted in ``BLOCK_ROWS``."""
     if cfg.block_rows is not None:
-        return cfg.block_rows
-    from repro.kernels import autotune
-    ent = autotune.lookup(kernel, rows, row_len, k, sign)
-    return ent.block_rows if ent is not None else DEFAULT_BLOCK_ROWS
+        br, how = cfg.block_rows, "explicit"
+    else:
+        from repro.kernels import autotune
+        ent = autotune.lookup(kernel, rows, row_len, k, sign)
+        if ent is None:
+            return None if kernel == "topk_compress" else DEFAULT_BLOCK_ROWS
+        br, how = ent.block_rows, "table"
+    if kernel == "topk_compress":
+        BLOCK_ROWS[(kernel, how, br)] += 1
+    return br
 
 
 def _compact_geometry(cfg: DispatchConfig, rows: int, row_len: int,

@@ -9,6 +9,8 @@ other just to count.
 
 from __future__ import annotations
 
+from collections import Counter
+
 LAUNCHES = {"topk_compress": 0, "topk_compact": 0, "qsgd": 0,
             "sparse_gemm": 0, "qdq_gemm": 0, "flash_decode": 0,
             "paged_decode": 0}
@@ -28,9 +30,18 @@ PAGE_POOL = {"pages_used": 0, "pages_free": 0, "peak_pages_used": 0,
 TUNE_CACHE = {"hit": 0, "miss": 0}
 
 
+#: trace-time block heights of ``topk_compress`` launches:
+#: ``(kernel, how, block_rows)`` -> launches, where ``how`` says what set
+#: the height — ``explicit`` (``DispatchConfig.block_rows``), ``table``
+#: (the autotune table) or ``rule`` (``topk_compress.dense_block_rows``
+#: of the rows the kernel tiles).  Cleared with the launch counts.
+BLOCK_ROWS: Counter = Counter()
+
+
 def reset_launches() -> None:
     for k in LAUNCHES:
         LAUNCHES[k] = 0
+    BLOCK_ROWS.clear()
 
 
 def reset_tune_cache() -> None:

@@ -22,8 +22,12 @@ Two emission modes share the threshold search:
     free (DESIGN.md §3.3).
 
 Grid: one program per row-block.  Block shape (ROWS, n) where n is the
-row length (the shard-local compression row, typically 1-8k) — (8, 512)
-multiples keep the VPU lanes full.
+row length (the shard-local compression row, typically 1-8k), a
+multiple of the 128 lanes.  Each block runs the bisection's chain of
+dependent cross-lane reductions once, whatever its height, so
+``topk_compress`` sizes ROWS from the shape it tiles
+(:func:`dense_block_rows`): as tall as a VMEM budget allows, to hide
+that chain behind more rows.
 """
 
 from __future__ import annotations
@@ -35,7 +39,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.launch_stats import LAUNCHES
+from repro.kernels.launch_stats import BLOCK_ROWS, LAUNCHES
 
 #: scoped VMEM the compression kernels may use (v5e has 128 MiB of
 #: VMEM; the compiler's default scope is 16 MiB).  At block_rows 8 the
@@ -45,6 +49,29 @@ VMEM_LIMIT_BYTES = 64 << 20
 COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 #: matmul precision of the one-hot slot scatters: f32 operands exact
 EXACT = jax.lax.Precision.HIGHEST
+#: VMEM of one (block_rows, n) f32 plane under :func:`dense_block_rows`:
+#: that of the 8-row block at 2^17 lanes, so its three planes,
+#: double-buffered, and its temporaries fit ``VMEM_LIMIT_BYTES`` at any
+#: row length (8 MiB planes do not).  On v5e a 4 MiB plane ran the
+#: kernel 10-12 % faster than a 2 MiB one at n = 4096 and 7168.
+DENSE_PLANE_BYTES = 4 << 20
+
+
+def dense_block_rows(rows: int, n: int) -> int:
+    """Block height of a ``topk_compress`` launch over [rows, n].
+
+    The largest power of two >= 8 that divides ``rows`` (so no launch
+    pads) and keeps a (block, n) f32 plane inside
+    ``DENSE_PLANE_BYTES``; 8 where none larger does (rows near
+    ``DispatchConfig.max_row``, row counts with no larger power-of-two
+    divisor); ``rows`` itself below 8 (global Top_k's single row).
+    """
+    if rows < 8:
+        return rows
+    br = 8
+    while rows % (2 * br) == 0 and 2 * br * n * 4 <= DENSE_PLANE_BYTES:
+        br *= 2
+    return br
 
 
 def out_struct(shape, dtype, like: jax.Array) -> jax.ShapeDtypeStruct:
@@ -100,16 +127,22 @@ def _kernel(acc_ref, sel_ref, mem_ref, cnt_ref, *, k: int, iters: int,
 
 
 def topk_compress(acc: jax.Array, k: int, *, iters: int = 24,
-                  sign: bool = False, block_rows: int = 8,
+                  sign: bool = False, block_rows: int | None = None,
                   interpret: bool = False):
     """acc: [rows, n] -> (selected [rows, n], new_mem [rows, n], cnt [rows]).
 
-    VMEM per program: 3 blocks of (block_rows, n) f32, double-buffered,
-    plus the kernel's temporaries — inside ``VMEM_LIMIT_BYTES`` up to
-    n = 2^17 at block_rows = 8 (``DispatchConfig.max_row``).
+    ``block_rows=None`` sizes the blocks from ``acc``'s own shape
+    (:func:`dense_block_rows`; inside a ``shard_map``, the shard-local
+    rows), counted in ``launch_stats.BLOCK_ROWS`` as ``rule``.  VMEM per
+    program: 3 blocks of (block_rows, n) f32, double-buffered, plus the
+    kernel's temporaries — inside ``VMEM_LIMIT_BYTES`` up to n = 2^17
+    at block_rows = 8 (``DispatchConfig.max_row``).
     """
     LAUNCHES["topk_compress"] += 1
     rows, n = acc.shape
+    if block_rows is None:
+        block_rows = dense_block_rows(rows, n)
+        BLOCK_ROWS[("topk_compress", "rule", block_rows)] += 1
     br = min(block_rows, rows)
     pad = (-rows) % br
     if pad:

@@ -4,8 +4,8 @@ DESIGN.md §10).
 The load-bearing invariants:
 
 * resolution order — explicit ``DispatchConfig(block_rows=...)`` beats
-  the tuning table beats the historical default, so untuned shapes and
-  off-TPU runs behave exactly as before the autotuner existed;
+  the tuning table beats the kernel's own geometry (``topk_compress``'s
+  shape rule, the fixed default of the other kernels);
 * robustness — corrupt, stale-schema or foreign-device table files
   load as empty with a once-per-reason warning, never an exception;
 * the geometry-transparency contract the whole feature rests on: the
@@ -24,7 +24,9 @@ import pytest
 from repro.kernels import autotune as at
 from repro.kernels import dispatch as dsp
 from repro.kernels import qsgd as qk
-from repro.kernels.launch_stats import TUNE_CACHE, reset_tune_cache
+from repro.kernels.launch_stats import (
+    BLOCK_ROWS, TUNE_CACHE, reset_tune_cache,
+)
 from tests.strategies import TUNE_GRID
 
 KCFG = dict(mode="kernel")   # force the kernel path (interpret on CPU)
@@ -138,12 +140,19 @@ def test_resolution_order():
     key = at.ShapeKey("topk_compress", 4, 512, 16, False)
     at.save_table({key.as_str(): _entry(2)})
     at.clear_cache()
+    dsp.reset_launches()
     tuned = dsp.DispatchConfig(**KCFG)                 # auto: table wins
     explicit = dsp.DispatchConfig(block_rows=3, **KCFG)
     assert dsp._block_rows(tuned, *key[:5]) == 2
     assert dsp._block_rows(explicit, *key[:5]) == 3    # explicit beats table
-    # untuned shape falls back to the historical heuristic
+    assert BLOCK_ROWS == {("topk_compress", "table", 2): 1,
+                          ("topk_compress", "explicit", 3): 1}
+    # an untuned Top_k shape is left to the kernel's rule, which sizes
+    # its blocks from the rows it tiles; the compact kernel and qsgd
+    # keep the fixed fallback
     assert dsp._block_rows(tuned, "topk_compress", 9, 640, 5,
+                           False) is None
+    assert dsp._block_rows(tuned, "qsgd", 9, 640, 5,
                            False) == dsp.DEFAULT_BLOCK_ROWS
     assert dsp._compact_geometry(tuned, 9, 640, 5, False) == (
         dsp.DEFAULT_BLOCK_ROWS, dsp.DEFAULT_CHUNK)

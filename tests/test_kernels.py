@@ -100,6 +100,86 @@ def test_topk_compress_selects_topk(rows, n, k):
                                rtol=1e-6, atol=1e-6)
 
 
+@pytest.mark.parametrize("sign", [False, True])
+@pytest.mark.parametrize("rows,n,k,height", [
+    (128, 256, 3, 128),      # the rule takes every row in one block
+    (32, 65536, 655, 16),    # the plane budget stops it below the rows
+    (24, 384, 4, 8),         # no power of two above 8 divides 24
+    (9, 256, 3, 8),          # 9 rows: the 8-row grid, padded
+    (1, 1024, 10, 1),        # global Top_k's single row
+])
+def test_topk_compress_rule_height_is_bit_identical(rows, n, k, height,
+                                                    sign):
+    """The kernel is row-independent: at the height ``block_rows=None``
+    picks from the shape, selections, memories and counts equal those
+    of the 8-row grid bit for bit."""
+    from repro.kernels import topk_compress as tk
+    assert tk.dense_block_rows(rows, n) == height
+    acc = jax.random.normal(jax.random.PRNGKey(rows + n), (rows, n))
+    auto = tk.topk_compress(acc, k, sign=sign, interpret=True)
+    eight = tk.topk_compress(acc, k, sign=sign, block_rows=8,
+                             interpret=True)
+    for a, b in zip(auto, eight):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _cell_launches():
+    """(config, rows, n) of every Top_k leaf of the training cells'
+    configurations: ``bench/work.leaf_rows`` at the program's dims."""
+    import json
+    import pathlib
+    from bench import model as bmodel
+    from bench import work
+    configs = pathlib.Path(__file__).resolve().parents[1] / "bench/configs"
+    out = set()
+    for name in ("yi-6b", "yi-34b"):
+        conf = json.loads((configs / f"{name}.json").read_text())
+        dm = bmodel.dims(bmodel.program_config(conf))
+        out |= {(name, r, n) for r, n in work.leaf_rows(dm)}
+    return sorted(out)
+
+
+@pytest.mark.parametrize("name,rows,n", _cell_launches())
+def test_dense_block_rows_at_cell_launches(name, rows, n):
+    """Every Top_k launch of the two training cells gets a power-of-two
+    height that divides its rows (no launch pads) inside the plane
+    budget, and more than 8 rows wherever its leaf has 16 or more."""
+    from repro.kernels import topk_compress as tk
+    br = tk.dense_block_rows(rows, n)
+    assert br & (br - 1) == 0 and rows % br == 0
+    assert br * n * 4 <= tk.DENSE_PLANE_BYTES
+    assert br > 8 or rows < 16
+
+
+@pytest.mark.parametrize("rows", [8, 9, 64, 64000])
+def test_dense_block_rows_at_max_row(rows):
+    """The longest row dispatch admits keeps the 8-row grid that
+    compiles for v5e at 2^17 lanes."""
+    from repro.kernels import dispatch as dsp
+    from repro.kernels import topk_compress as tk
+    assert tk.dense_block_rows(rows, dsp.DispatchConfig().max_row) == 8
+
+
+def test_block_rows_counter_records_each_launch():
+    """``launch_stats.BLOCK_ROWS`` counts one entry per ``topk_compress``
+    call at trace time, by what set its height: the rule at the height
+    it picked, an explicit ``DispatchConfig.block_rows`` as given."""
+    from repro.kernels import dispatch as dsp
+    from repro.kernels.launch_stats import BLOCK_ROWS, LAUNCHES
+    auto = dsp.DispatchConfig(mode="kernel")
+    explicit = dsp.DispatchConfig(mode="kernel", block_rows=8)
+    x = jnp.zeros((64, 512), jnp.float32)
+    dsp.reset_launches()
+    jax.make_jaxpr(lambda a: [dsp.topk_rows(a, 5, cfg=auto),
+                              dsp.topk_rows(a, 5, sign=True, cfg=auto),
+                              dsp.topk_rows(a, 5, cfg=explicit)])(x)
+    assert LAUNCHES["topk_compress"] == 3
+    assert dict(BLOCK_ROWS) == {("topk_compress", "rule", 64): 2,
+                                ("topk_compress", "explicit", 8): 1}
+    dsp.reset_launches()
+    assert not BLOCK_ROWS
+
+
 @pytest.mark.parametrize("B,S,H,KV,D,w", [
     (2, 64, 4, 2, 32, -1),
     (1, 100, 4, 4, 16, -1),      # ragged S vs block

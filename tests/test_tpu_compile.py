@@ -66,6 +66,21 @@ def test_topk_compress_compiles(one_chip):
              ((V, D), jnp.float32))
 
 
+@pytest.mark.parametrize("rows,n,k,height", [
+    (20480, 7168, 72, 128),  # yi-34b's widest Top_k launch (w1, w3)
+    (V, D, K, 256),          # yi-6b's embedding and head
+    (8, 1 << 17, K, 8),      # the longest row dispatch admits
+    (65536, 256, 3, 4096),   # a short row: its (rows, 1) vectors weigh most
+])
+def test_topk_compress_compiles_at_rule_height(one_chip, rows, n, k,
+                                               height):
+    """The height ``topk_compress`` picks from its shape fits v5e's
+    scoped VMEM: three planes double-buffered plus the temporaries."""
+    assert tk.dense_block_rows(rows, n) == height
+    _compile(lambda a: tk.topk_compress(a, k, interpret=False), one_chip,
+             ((rows, n), jnp.float32))
+
+
 def test_topk_compact_compiles(one_chip):
     _compile(lambda a: tk.topk_compact(a, K, KCAP, interpret=False),
              one_chip, ((V, D), jnp.float32))
